@@ -65,7 +65,6 @@ class ArchConfig:
     param_dtype: str = "bfloat16"
     mc: MemoryControllerConfig = dataclasses.field(
         default_factory=MemoryControllerConfig)
-    use_pallas: bool = False               # TPU kernels (interpret-tested)
     remat: bool = True
     # "nothing" recomputes the whole layer in backward (min memory, max
     # recompute: 3 weight-gather passes); "dots" saves matmul outputs

@@ -19,8 +19,7 @@ def _next_pow2(n: int) -> int:
     return 1 << max(1, (n - 1).bit_length())
 
 
-def sort_with_indices(keys: jnp.ndarray, vals: jnp.ndarray | None = None,
-                      *, interpret: bool = True):
+def sort_with_indices(keys: jnp.ndarray, vals: jnp.ndarray | None = None):
     """Stable-sort ``keys`` (1-D or (G, N)) via the Pallas network.
 
     Returns (sorted_keys, perm) when ``vals`` is None else
@@ -37,8 +36,7 @@ def sort_with_indices(keys: jnp.ndarray, vals: jnp.ndarray | None = None,
         k2 = jnp.pad(k2, ((0, 0), (0, n_pad - n)),
                      constant_values=_SENTINEL)
         v2 = jnp.pad(v2, ((0, 0), (0, n_pad - n)))
-    skeys, perm, svals = bitonic_sort_batched(k2.astype(jnp.int32),
-                                              v2, interpret=interpret)
+    skeys, perm, svals = bitonic_sort_batched(k2.astype(jnp.int32), v2)
     skeys, perm, svals = skeys[:, :n], perm[:, :n], svals[:, :n]
     if squeeze:
         skeys, perm, svals = skeys[0], perm[0], svals[0]

@@ -22,11 +22,11 @@ from repro.kernels.cache_lookup.kernel import cache_probe
 
 
 def cache_service(table: jnp.ndarray, line_ids: jnp.ndarray,
-                  state: CacheState, *, interpret: bool = True):
+                  state: CacheState):
     """Returns (lines (N, d), hits (N,), new_state)."""
     hits, ways, tags, valid, age, clock = cache_probe(
         line_ids, state.tags, state.valid.astype(jnp.int32),
-        state.age, state.clock, interpret=interpret)
+        state.age, state.clock)
 
     num_sets = state.tags.shape[0]
     set_idx = line_ids % num_sets

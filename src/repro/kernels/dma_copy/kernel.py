@@ -10,6 +10,10 @@ and ``max_transaction_bytes`` maps to the chunk (block) size.
 Structure per chunk ``c`` on channel ``ch = c % channels``:
   wait outbound[ch] (slot free) → start inbound c → ... (channels in
   flight) ... → wait inbound[ch] → start outbound c.
+
+Each chunk is a ``(rows, 128)`` tile stack, so a channel slot is a whole
+leading-axis slice of the ``(channels, rows, 128)`` staging buffer: the
+TPU only slices VMEM along untiled axes or at (8, 128) tile boundaries.
 """
 
 from __future__ import annotations
@@ -20,6 +24,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import interpret_default
 
 
 def _dma_copy_kernel(in_ref, out_ref, scratch, in_sems, out_sems,
@@ -67,18 +73,19 @@ def _dma_copy_kernel(in_ref, out_ref, scratch, in_sems, out_sems,
 
 @functools.partial(jax.jit, static_argnames=("channels", "interpret"))
 def dma_copy_chunked(src: jnp.ndarray, *, channels: int = 4,
-                     interpret: bool = True) -> jnp.ndarray:
-    """Copy ``src (num_chunks, chunk_elems)`` through the staging pipeline."""
-    num_chunks, chunk = src.shape
+                     interpret: bool | None = None) -> jnp.ndarray:
+    """Copy ``src (num_chunks, rows, 128)`` through the staging pipeline.
+    ``interpret=None`` interprets on the CPU backend only."""
+    num_chunks, rows, lanes = src.shape
     return pl.pallas_call(
         functools.partial(_dma_copy_kernel, channels=channels),
         in_specs=[pl.BlockSpec(memory_space=pl.MemorySpace.ANY)],
         out_specs=pl.BlockSpec(memory_space=pl.MemorySpace.ANY),
-        out_shape=jax.ShapeDtypeStruct((num_chunks, chunk), src.dtype),
+        out_shape=jax.ShapeDtypeStruct(src.shape, src.dtype),
         scratch_shapes=[
-            pltpu.VMEM((channels, chunk), src.dtype),
+            pltpu.VMEM((channels, rows, lanes), src.dtype),
             pltpu.SemaphoreType.DMA((channels,)),
             pltpu.SemaphoreType.DMA((channels,)),
         ],
-        interpret=interpret,
+        interpret=interpret_default() if interpret is None else interpret,
     )(src)

@@ -25,6 +25,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import interpret_default
+
 NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 
 
@@ -99,7 +101,7 @@ def flash_attention_pallas(
     window=None,
     q_block: int = 128,
     kv_block: int = 128,
-    interpret: bool = True,
+    interpret: bool | None = None,   # None: interpret on the CPU backend
 ):
     BH, S, hd = q.shape
     scale = hd ** -0.5
@@ -135,5 +137,5 @@ def flash_attention_pallas(
             pltpu.VMEM((q_block, 1), jnp.float32),
             pltpu.VMEM((q_block, 1), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=interpret_default() if interpret is None else interpret,
     )(q, k, v)

@@ -8,7 +8,7 @@ from repro.kernels.flash_attention.kernel import flash_attention_pallas
 
 
 def flash_attention(q, k, v, *, causal=True, window=None,
-                    q_block=128, kv_block=128, interpret=True):
+                    q_block=128, kv_block=128):
     """GQA flash attention; value-matches ``ref.attention_ref``."""
     B, S, H, hd = q.shape
     KV = k.shape[2]
@@ -20,5 +20,5 @@ def flash_attention(q, k, v, *, causal=True, window=None,
     kv_block = min(kv_block, S)
     out = flash_attention_pallas(qf, kf, vf, group=G, causal=causal,
                                  window=window, q_block=q_block,
-                                 kv_block=kv_block, interpret=interpret)
+                                 kv_block=kv_block)
     return out.reshape(B, H, S, hd).transpose(0, 2, 1, 3)

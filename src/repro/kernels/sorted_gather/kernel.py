@@ -8,9 +8,11 @@ consecutive grid steps map to the same block — so after sorting, duplicate
 rows cost **zero additional HBM traffic**, exactly the row-buffer-hit
 economics of the paper (and why the wrapper sorts first).
 
-Block shape: one table row per grid step, padded to the (8, 128)-lane
-layout by the compiler; rows are contiguous HBM bursts, so the sorted
-stream is also quasi-sequential for the HBM controller.
+Block shape: one table row per grid step. The table is viewed as
+``(rows, 1, d)`` and the leading block dimension is squeezed, so the last
+two block dimensions equal the array's — the TPU's (8, 128) tiling rule
+holds at any ``d`` — and each step moves one contiguous HBM burst; the
+sorted stream is also quasi-sequential for the HBM controller.
 """
 
 from __future__ import annotations
@@ -22,6 +24,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import interpret_default
+
 
 def _gather_row_kernel(idx_ref, table_ref, out_ref):
     # idx_ref is the scalar-prefetch operand; the index map already steered
@@ -30,23 +34,25 @@ def _gather_row_kernel(idx_ref, table_ref, out_ref):
     out_ref[...] = table_ref[...]
 
 
-@functools.partial(jax.jit, static_argnames=("rows_per_step", "interpret"))
+@functools.partial(jax.jit, static_argnames=("interpret",))
 def gather_rows(table: jnp.ndarray, sorted_idx: jnp.ndarray,
-                *, rows_per_step: int = 1, interpret: bool = True):
+                *, interpret: bool | None = None):
     """Gather ``table[sorted_idx]``; callers must pass sorted indices to get
-    the dedup/locality behaviour (unsorted input is still correct)."""
+    the dedup/locality behaviour (unsorted input is still correct).
+    ``interpret=None`` interprets on the CPU backend only."""
     n = sorted_idx.shape[0]
-    d = table.shape[1]
-    assert rows_per_step == 1, "one row per grid step (revisit-dedup unit)"
+    rows, d = table.shape
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(n,),
-        in_specs=[pl.BlockSpec((1, d), lambda i, idx_ref: (idx_ref[i], 0))],
-        out_specs=pl.BlockSpec((1, d), lambda i, idx_ref: (i, 0)),
+        in_specs=[pl.BlockSpec((None, 1, d),
+                               lambda i, idx_ref: (idx_ref[i], 0, 0))],
+        out_specs=pl.BlockSpec((None, 1, d), lambda i, idx_ref: (i, 0, 0)),
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         _gather_row_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n, d), table.dtype),
-        interpret=interpret,
-    )(sorted_idx.astype(jnp.int32), table)
+        out_shape=jax.ShapeDtypeStruct((n, 1, d), table.dtype),
+        interpret=interpret_default() if interpret is None else interpret,
+    )(sorted_idx.astype(jnp.int32), table.reshape(rows, 1, d))
+    return out.reshape(n, d)

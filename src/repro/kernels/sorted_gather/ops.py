@@ -16,15 +16,14 @@ from repro.kernels.sorted_gather.kernel import gather_rows
 
 
 def sorted_gather(table: jnp.ndarray, indices: jnp.ndarray,
-                  *, use_bitonic: bool = False,
-                  interpret: bool = True) -> jnp.ndarray:
+                  *, use_bitonic: bool = False) -> jnp.ndarray:
     idx = indices.reshape(-1)
     if use_bitonic:
-        _, perm = bitonic_ops.sort_with_indices(idx, interpret=interpret)
+        _, perm = bitonic_ops.sort_with_indices(idx)
     else:
         perm = jnp.argsort(idx, stable=True)
     sorted_idx = jnp.take(idx, perm, axis=0)
-    gathered = gather_rows(table, sorted_idx, interpret=interpret)
+    gathered = gather_rows(table, sorted_idx)
     inv_perm = jnp.argsort(perm, stable=True)
     out = jnp.take(gathered, inv_perm, axis=0)
     return out.reshape(*indices.shape, table.shape[-1])

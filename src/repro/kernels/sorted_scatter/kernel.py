@@ -25,6 +25,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import interpret_default
+
 
 def _scatter_row_kernel(idx_ref, val_ref, table_ref, out_ref):
     # idx_ref (scalar prefetch) already steered the output pipeline to row
@@ -36,29 +38,34 @@ def _scatter_row_kernel(idx_ref, val_ref, table_ref, out_ref):
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def scatter_rows(table: jnp.ndarray, sorted_idx: jnp.ndarray,
-                 values: jnp.ndarray, *, interpret: bool = True):
+                 values: jnp.ndarray, *, interpret: bool | None = None):
     """Write ``values[i]`` to ``table[sorted_idx[i]]``, last writer wins.
 
     Callers must pass indices sorted (stably) by row to get the VMEM
     coalescing and HBM locality; *correctness* additionally requires equal
     indices to be adjacent, which sorting guarantees — with non-adjacent
     duplicates an earlier flushed block could clobber a later one.
+    Rows use the ``(rows, 1, d)`` view of ``sorted_gather``'s kernel.
+    ``interpret=None`` interprets on the CPU backend only.
     """
     n = sorted_idx.shape[0]
-    d = table.shape[1]
+    rows, d = table.shape
+    row = lambda f: pl.BlockSpec((None, 1, d), f)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(n,),
         in_specs=[
-            pl.BlockSpec((1, d), lambda i, idx_ref: (i, 0)),           # values
-            pl.BlockSpec((1, d), lambda i, idx_ref: (idx_ref[i], 0)),  # table
+            row(lambda i, idx_ref: (i, 0, 0)),            # values
+            row(lambda i, idx_ref: (idx_ref[i], 0, 0)),   # table
         ],
-        out_specs=pl.BlockSpec((1, d), lambda i, idx_ref: (idx_ref[i], 0)),
+        out_specs=row(lambda i, idx_ref: (idx_ref[i], 0, 0)),
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         _scatter_row_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(table.shape, table.dtype),
+        out_shape=jax.ShapeDtypeStruct((rows, 1, d), table.dtype),
         input_output_aliases={2: 0},   # table buffer is updated in place
-        interpret=interpret,
-    )(sorted_idx.astype(jnp.int32), values, table)
+        interpret=interpret_default() if interpret is None else interpret,
+    )(sorted_idx.astype(jnp.int32), values.reshape(n, 1, d),
+      table.reshape(rows, 1, d))
+    return out.reshape(rows, d)

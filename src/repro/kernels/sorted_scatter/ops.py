@@ -27,7 +27,6 @@ from repro.kernels.sorted_scatter.kernel import scatter_rows
 def sorted_scatter(table: jnp.ndarray, indices: jnp.ndarray,
                    values: jnp.ndarray, *, mode: str = "set",
                    use_bitonic: bool = False,
-                   interpret: bool = True,
                    backend: str = "pallas") -> jnp.ndarray:
     """One sort-and-coalesce pipeline for both data planes: the Pallas
     kernel (``backend="pallas"``) and the XLA fallback the controller
@@ -39,7 +38,7 @@ def sorted_scatter(table: jnp.ndarray, indices: jnp.ndarray,
     idx = indices.reshape(-1)
     vals = values.reshape(idx.shape[0], table.shape[-1])
     if use_bitonic:
-        _, perm = bitonic_ops.sort_with_indices(idx, interpret=interpret)
+        _, perm = bitonic_ops.sort_with_indices(idx)
     else:
         perm = jnp.argsort(idx, stable=True)
     sidx = jnp.take(idx, perm, axis=0)
@@ -49,7 +48,7 @@ def sorted_scatter(table: jnp.ndarray, indices: jnp.ndarray,
         # block is flushed — holds table[row] + Σ(run values).
         svals = coalesce_add_runs(table, sidx, svals)
     if backend == "pallas":
-        return scatter_rows(table, sidx, svals, interpret=interpret)
+        return scatter_rows(table, sidx, svals)
     n = sidx.shape[0]
     is_last = jnp.concatenate(
         [sidx[1:] != sidx[:-1], jnp.ones((1,), bool)]) if n else \

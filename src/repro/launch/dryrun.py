@@ -43,15 +43,11 @@ from repro.data.synthetic import batch_specs
 from repro.launch import roofline
 from repro.launch.mesh import make_production_mesh
 from repro.models.lm import build_lm
+from repro.models.sharding import named_shardings
 from repro.optim.adamw import (OptimizerConfig, abstract_opt_state,
                                adamw_update, opt_state_specs)
 
 MARGIN = 256   # decode cache slack; multiple of 256 keeps seq-sharding even
-
-
-def _named(mesh, spec_tree):
-    return jax.tree.map(lambda s: NamedSharding(mesh, s), spec_tree,
-                        is_leaf=lambda x: isinstance(x, P))
 
 
 def estimate_state_bytes_per_device(abstract_tree, spec_tree, mesh) -> float:
@@ -113,8 +109,9 @@ def build_cell(arch_name: str, shape_name: str, mesh, *,
 
         fn = jax.jit(
             train_step,
-            in_shardings=(_named(mesh, pspecs), _named(mesh, ospecs),
-                          _named(mesh, bspecs)),
+            in_shardings=(named_shardings(mesh, pspecs),
+                          named_shardings(mesh, ospecs),
+                          named_shardings(mesh, bspecs)),
             donate_argnums=(0, 1),
         )
         args = (aparams, aopt, bshapes)
@@ -131,8 +128,9 @@ def build_cell(arch_name: str, shape_name: str, mesh, *,
 
         fn = jax.jit(
             serve_prefill,
-            in_shardings=(_named(mesh, pspecs), _named(mesh, bspecs)),
-            out_shardings=(None, _named(mesh, cspecs), None),
+            in_shardings=(named_shardings(mesh, pspecs),
+                          named_shardings(mesh, bspecs)),
+            out_shardings=(None, named_shardings(mesh, cspecs), None),
         )
         args = (aparams, bshapes)
 
@@ -147,9 +145,9 @@ def build_cell(arch_name: str, shape_name: str, mesh, *,
 
         fn = jax.jit(
             serve_step,
-            in_shardings=(_named(mesh, pspecs),
+            in_shardings=(named_shardings(mesh, pspecs),
                           NamedSharding(mesh, rules.spec("batch")),
-                          _named(mesh, cspecs),
+                          named_shardings(mesh, cspecs),
                           NamedSharding(mesh, P())),
             donate_argnums=(2,),
         )

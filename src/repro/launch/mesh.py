@@ -1,4 +1,4 @@
-"""Production mesh construction.
+"""Mesh construction: the repo's one mesh helper and the production mesh.
 
 Defined as functions (never module-level constants) so importing this
 module can never touch jax device state — required for the dry-run's
@@ -12,31 +12,30 @@ device count survives a failure.
 
 from __future__ import annotations
 
+import math
 from typing import Optional, Sequence
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape: Sequence[int], axes: Sequence[str],
+              devices: Optional[Sequence] = None):
+    """The repo's one mesh constructor: the first ``prod(shape)`` devices
+    with ``Auto`` axes. ``jax.make_mesh`` defaults to ``Explicit`` axes,
+    which reject the model's logical ``with_sharding_constraint``s."""
+    need = math.prod(shape)
+    devices = jax.devices() if devices is None else devices
+    if len(devices) < need:
+        raise RuntimeError(
+            f"mesh {tuple(shape)} needs {need} devices, have {len(devices)}")
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(shape),
+                         devices=list(devices)[:need])
 
 
 def make_production_mesh(*, multi_pod: bool = False,
                          devices: Optional[Sequence] = None):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    need = 1
-    for s in shape:
-        need *= s
-    if devices is None:
-        devices = jax.devices()
-    if len(devices) < need:
-        raise RuntimeError(
-            f"production mesh needs {need} devices, have {len(devices)} "
-            "(dry-run must set --xla_force_host_platform_device_count)")
-    return jax.make_mesh(shape, axes, devices=devices[:need])
-
-
-def make_test_mesh(shape=(2, 2), axes=("data", "model")):
-    """Small mesh for CPU distribution tests (8 fake devices)."""
-    need = 1
-    for s in shape:
-        need *= s
-    devs = jax.devices()[:need]
-    return jax.make_mesh(shape, axes, devices=devs)
+    return make_mesh(shape, axes, devices)

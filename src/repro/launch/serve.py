@@ -36,6 +36,7 @@ from repro.configs import get_arch
 from repro.core.config import MemoryControllerConfig, SchedulerConfig
 from repro.core.controller import MemoryController
 from repro.core.scheduler import form_batches
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.lm import build_lm
 
 #: KV page granularity of the modeled access stream (bytes per token row)
@@ -252,6 +253,7 @@ def main() -> None:
                     help="modeled sojourn SLO; turns on per-tenant "
                          "attainment attribution")
     args = ap.parse_args()
+    use_compile_cache()
 
     server = Server(args.arch, smoke=args.smoke,
                     slo_cycles=args.slo_cycles)

@@ -25,12 +25,13 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.checkpoint import CheckpointManager
 from repro.configs import SHAPES, ShapeConfig, get_arch
 from repro.data.synthetic import SyntheticDataset
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.lm import build_lm
+from repro.models.sharding import named_shardings
 from repro.optim.adamw import (OptimizerConfig, adamw_update, init_opt_state,
                                opt_state_specs)
 from repro.runtime import StepWatchdog, plan_rescale
@@ -87,23 +88,24 @@ class Trainer:
                      if tc.ckpt_dir else None)
 
         step_fn = make_train_step(self.lm, tc.opt)
+        self._init_opt = init_opt_state
         if mesh is not None:
             pspecs = self.lm.param_specs()
-            ospecs = opt_state_specs(pspecs)
-            named = lambda t: jax.tree.map(
-                lambda s: NamedSharding(mesh, s), t,
-                is_leaf=lambda x: isinstance(x, P))
+            ospecs = named_shardings(mesh, opt_state_specs(pspecs))
             self.step_fn = jax.jit(step_fn,
-                                   in_shardings=(named(pspecs),
-                                                 named(ospecs), None),
+                                   in_shardings=(
+                                       named_shardings(mesh, pspecs),
+                                       ospecs, None),
                                    donate_argnums=(0, 1))
+            # Adam moments are created in their shards, like the params.
+            self._init_opt = jax.jit(init_opt_state, out_shardings=ospecs)
         else:
             self.step_fn = jax.jit(step_fn, donate_argnums=(0, 1))
 
     # -- state ---------------------------------------------------------------
     def init_state(self):
         params = self.lm.init(jax.random.key(self.tc.seed))
-        return params, init_opt_state(params), 0
+        return params, self._init_opt(params), 0
 
     def restore_or_init(self):
         params, opt_state, start = self.init_state()
@@ -173,6 +175,7 @@ def main() -> None:
     ap.add_argument("--ckpt-every", type=int, default=50)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    use_compile_cache()
     tc = TrainerConfig(arch=args.arch, shape=args.shape, smoke=args.smoke,
                        steps=args.steps, batch_override=args.batch,
                        seq_override=args.seq, ckpt_dir=args.ckpt_dir,

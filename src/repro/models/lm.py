@@ -15,6 +15,7 @@ Entry points (the shape cells map onto these):
 from __future__ import annotations
 
 import dataclasses
+from functools import partial
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -25,7 +26,8 @@ from repro.models import blocks, layers
 from repro.models.blocks import AttnCache, MambaCache
 from repro.models.params import (abstract_params, init_params, mamba_dims,
                                  param_specs)
-from repro.models.sharding import Rules, make_rules, shard
+from repro.models.sharding import (Rules, make_rules, named_shardings,
+                                   shard)
 
 
 @dataclasses.dataclass
@@ -37,7 +39,13 @@ class LM:
 
     # ---------------- params ------------------------------------------------
     def init(self, key):
-        return init_params(self.cfg, key)
+        """Random parameters. On a mesh each leaf is created in its own
+        sharding, so no device ever holds the whole model."""
+        if self.mesh is None:
+            return init_params(self.cfg, key)
+        return jax.jit(partial(init_params, self.cfg),
+                       out_shardings=named_shardings(
+                           self.mesh, self.param_specs()))(key)
 
     def abstract_params(self):
         return abstract_params(self.cfg)

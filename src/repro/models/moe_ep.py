@@ -30,7 +30,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.configs.base import ArchConfig
 from repro.core import capture as capture_mod
 from repro.models import layers
@@ -66,7 +65,7 @@ def moe_ffn_ep(p, x, cfg: ArchConfig, mesh, *, no_drop: bool = False):
         capture_moe_dispatch(top_e_g, B * S, D, jnp.dtype(x.dtype).itemsize)
 
     @partial(
-        compat.shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(
             {"ln": P(), "router": P(),

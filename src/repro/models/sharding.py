@@ -130,5 +130,7 @@ def _in_jit() -> bool:
     return True  # constraints are harmless outside jit in recent JAX
 
 
-def named_sharding(mesh: Mesh, spec: P) -> NamedSharding:
-    return NamedSharding(mesh, spec)
+def named_shardings(mesh: Mesh, spec_tree):
+    """``NamedSharding`` on ``mesh`` for every PartitionSpec of a tree."""
+    return jax.tree.map(lambda s: NamedSharding(mesh, s), spec_tree,
+                        is_leaf=lambda x: isinstance(x, P))

@@ -14,7 +14,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Optional, Tuple
 
-import jax
+from repro.launch.mesh import make_mesh
 
 
 @dataclasses.dataclass(frozen=True)
@@ -72,4 +72,4 @@ def plan_rescale(old_shape: Tuple[int, ...], axis_names: Tuple[str, ...],
 
 
 def make_mesh_from_plan(plan: RescalePlan):
-    return jax.make_mesh(plan.new_shape, plan.axis_names)
+    return make_mesh(plan.new_shape, plan.axis_names)

@@ -81,11 +81,12 @@ def test_compressed_psum_error_feedback_unbiased():
 
     def one(axis_g, axis_r):
         # single-device psum via shard_map over a trivial mesh
-        from repro.compat import P, shard_map
-        mesh = jax.make_mesh((1,), ("pod",))
-        f = shard_map(
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((1,), ("pod",))
+        f = jax.shard_map(
             lambda gg, rr: compressed_psum(gg, rr, "pod", mode="int8"),
-            mesh=mesh, in_specs=(P(), P()), out_specs=(P(), P()))
+            mesh=mesh, in_specs=(jax.P(), jax.P()),
+            out_specs=(jax.P(), jax.P()))
         return f(axis_g, axis_r)
 
     for _ in range(50):
