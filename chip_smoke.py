@@ -36,6 +36,7 @@ from __future__ import annotations
 import argparse
 import dataclasses
 import json
+import os
 import sys
 import time
 from pathlib import Path
@@ -305,9 +306,9 @@ def phase_train_sharded(seed: int, *, smoke: bool = False, steps: int = 8,
                         batch: int = 8, seq: int = 512) -> dict:
     import jax
     import jax.numpy as jnp
-    from repro.launch.mesh import make_mesh
     from repro.launch.train import Trainer, TrainerConfig
     from repro.models.lm import build_lm
+    from repro.models.sharding import make_mesh
     from repro.optim.adamw import OptimizerConfig
 
     mesh = make_mesh((2, 2), ("data", "model"))
@@ -372,6 +373,9 @@ def main(argv=None) -> int:
               file=sys.stderr)
         return 2
     sys.path.insert(0, str(ROOT / "src"))
+    # The TPU library writes its logs under /tmp unless told otherwise.
+    (ROOT / ".tpu_logs").mkdir(exist_ok=True)
+    os.environ.setdefault("TPU_LOG_DIR", str(ROOT / ".tpu_logs"))
 
     import jax
     from repro.launch.compile_cache import use_compile_cache

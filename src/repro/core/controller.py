@@ -69,7 +69,8 @@ def sorted_scatter(
     stable sort keeps same-address arrival order so the last writer wins
     (weak-consistency rule); for ``mode="add"`` each run accumulates in
     promoted (≥f32) precision and rounds to the table dtype once.
-    Duplicate rows are coalesced — one HBM burst per distinct row. Thin
+    Duplicate rows are coalesced — each touched HBM tile is written back
+    once. Thin
     wrapper over the single sort-and-coalesce pipeline in
     ``repro.kernels.sorted_scatter.ops``.
     """

@@ -7,7 +7,7 @@ accumulation). The request stream is stable-sorted by row id (the
 scheduler's WRITE batch reorder), duplicate-row writes are coalesced —
 ``add`` folds each run into a single row update via a within-run prefix
 sum, ``set`` relies on VMEM overwrite inside the kernel — and the Pallas
-scatter streams one HBM burst per distinct row.
+scatter writes each touched HBM tile back once.
 
 No unsort step is needed on the write path: writes return no payload, so
 arrival order only matters *per address*, which the stable sort preserves

@@ -1,37 +1,20 @@
-"""Mesh construction: the repo's one mesh helper and the production mesh.
+"""The production mesh.
 
-Defined as functions (never module-level constants) so importing this
+Defined as a function (never a module-level constant) so importing this
 module can never touch jax device state — required for the dry-run's
 XLA_FLAGS ordering contract.
 
 Topology: one v5e pod contributes a 16x16 (data, model) mesh (256 chips);
-multi-pod prepends a pure-DP ``pod`` axis (2x16x16 = 512 chips). The same
-functions serve the elastic runtime, which re-invokes them with whatever
-device count survives a failure.
+multi-pod prepends a pure-DP ``pod`` axis (2x16x16 = 512 chips). Meshes
+of any other shape, such as the elastic runtime's after a failure, come
+from the same constructor, ``models.sharding.make_mesh``.
 """
 
 from __future__ import annotations
 
-import math
 from typing import Optional, Sequence
 
-import jax
-from jax.sharding import AxisType
-
-
-def make_mesh(shape: Sequence[int], axes: Sequence[str],
-              devices: Optional[Sequence] = None):
-    """The repo's one mesh constructor: the first ``prod(shape)`` devices
-    with ``Auto`` axes. ``jax.make_mesh`` defaults to ``Explicit`` axes,
-    which reject the model's logical ``with_sharding_constraint``s."""
-    need = math.prod(shape)
-    devices = jax.devices() if devices is None else devices
-    if len(devices) < need:
-        raise RuntimeError(
-            f"mesh {tuple(shape)} needs {need} devices, have {len(devices)}")
-    return jax.make_mesh(tuple(shape), tuple(axes),
-                         axis_types=(AxisType.Auto,) * len(shape),
-                         devices=list(devices)[:need])
+from repro.models.sharding import make_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False,

@@ -88,7 +88,7 @@ class Trainer:
                      if tc.ckpt_dir else None)
 
         step_fn = make_train_step(self.lm, tc.opt)
-        self._init_opt = init_opt_state
+        ospecs = None
         if mesh is not None:
             pspecs = self.lm.param_specs()
             ospecs = named_shardings(mesh, opt_state_specs(pspecs))
@@ -97,10 +97,11 @@ class Trainer:
                                        named_shardings(mesh, pspecs),
                                        ospecs, None),
                                    donate_argnums=(0, 1))
-            # Adam moments are created in their shards, like the params.
-            self._init_opt = jax.jit(init_opt_state, out_shardings=ospecs)
         else:
             self.step_fn = jax.jit(step_fn, donate_argnums=(0, 1))
+        # Adam moments are created where they live (in their shards on a
+        # mesh), never whole on one device first.
+        self._init_opt = jax.jit(init_opt_state, out_shardings=ospecs)
 
     # -- state ---------------------------------------------------------------
     def init_state(self):

@@ -15,10 +15,11 @@ Conventions:
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional, Tuple, Union
+import math
+from typing import Optional, Sequence, Tuple, Union
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 Axis = Union[None, str, Tuple[str, ...]]
 
@@ -128,6 +129,21 @@ def shard(x, rules: Rules, *logical, mesh: Optional[Mesh] = None):
 
 def _in_jit() -> bool:
     return True  # constraints are harmless outside jit in recent JAX
+
+
+def make_mesh(shape: Sequence[int], axes: Sequence[str],
+              devices: Optional[Sequence] = None) -> Mesh:
+    """The repo's one mesh constructor: the first ``prod(shape)`` devices
+    with ``Auto`` axes. ``jax.make_mesh`` defaults to ``Explicit`` axes,
+    which reject the logical ``with_sharding_constraint``s of ``shard``."""
+    need = math.prod(shape)
+    devices = jax.devices() if devices is None else devices
+    if len(devices) < need:
+        raise RuntimeError(
+            f"mesh {tuple(shape)} needs {need} devices, have {len(devices)}")
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(shape),
+                         devices=list(devices)[:need])
 
 
 def named_shardings(mesh: Mesh, spec_tree):

@@ -14,7 +14,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Optional, Tuple
 
-from repro.launch.mesh import make_mesh
+from repro.models.sharding import make_mesh
 
 
 @dataclasses.dataclass(frozen=True)

@@ -30,7 +30,7 @@ def run_sub(body: str) -> dict:
         import jax
         import jax.numpy as jnp
         import numpy as np
-        from repro.launch.mesh import make_mesh
+        from repro.models.sharding import make_mesh
     """) + textwrap.dedent(body)
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(REPO, "src")

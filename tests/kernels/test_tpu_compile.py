@@ -11,7 +11,15 @@ requests, the default controller cache and DMA configs, and attention at
 32 heads x 80 head dim over 512 tokens. The topology is described inside
 a fixture only: describing it loads the TPU library, which one process at
 a time may hold.
+
+The row kernels must also read the table where it lies: a relayout of
+the 32000 x 2560 table for the kernel (a ``copy`` or ``transpose`` of
+it, or a table-sized temporary) would cost more HBM traffic than the
+gather itself and would undo the scatter's in-place update.
 """
+
+import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -24,9 +32,13 @@ ROWS, D, N = 32000, 2560, 4096
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def one_chip(tmp_path_factory):
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
+    # Loading the TPU library would otherwise write its logs under /tmp
+    # (with TPU_LOG_DIR=disabled too); keep them in this run's own tmp.
+    os.environ.setdefault("TPU_LOG_DIR",
+                          str(tmp_path_factory.mktemp("tpu_logs")))
     try:
         topo = topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
@@ -45,25 +57,37 @@ def _spec(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
+def _compile(fn, *args, donate=()):
+    return jax.jit(fn, donate_argnums=donate).lower(*args).compile()
+
+
 def _compiled_text(fn, *args) -> str:
-    return jax.jit(fn).lower(*args).compile().as_text()
+    return _compile(fn, *args).as_text()
 
 
-def _gather(one_chip):
+def _gather_program(one_chip):
     from repro.kernels.sorted_gather.kernel import gather_rows
-    return _compiled_text(
+    return _compile(
         lambda t, i: gather_rows(t, i, interpret=False),
         _spec((ROWS, D), jnp.bfloat16, one_chip),
         _spec((N,), jnp.int32, one_chip))
 
 
-def _scatter(one_chip):
+def _scatter_program(one_chip):
     from repro.kernels.sorted_scatter.kernel import scatter_rows
-    return _compiled_text(
+    return _compile(
         lambda t, i, v: scatter_rows(t, i, v, interpret=False),
         _spec((ROWS, D), jnp.bfloat16, one_chip),
         _spec((N,), jnp.int32, one_chip),
-        _spec((N, D), jnp.bfloat16, one_chip))
+        _spec((N, D), jnp.bfloat16, one_chip), donate=(0,))
+
+
+def _gather(one_chip):
+    return _gather_program(one_chip).as_text()
+
+
+def _scatter(one_chip):
+    return _scatter_program(one_chip).as_text()
 
 
 def _bitonic(one_chip):
@@ -115,3 +139,19 @@ def _flash(one_chip):
                               "flash_attention"])
 def test_kernel_compiles_for_v5e(build, one_chip):
     assert "tpu_custom_call" in build(one_chip)
+
+
+@pytest.mark.parametrize("program,aliased",
+                         [(_gather_program, 0),
+                          (_scatter_program, ROWS * D * 2)],
+                         ids=["sorted_gather", "sorted_scatter"])
+def test_row_kernel_reads_table_in_place(program, aliased, one_chip):
+    compiled = program(one_chip)
+    table_shape = re.compile(rf"= \w+\[{ROWS},[^=]*\b(copy|transpose)\(")
+    copies = [line.strip() for line in compiled.as_text().splitlines()
+              if table_shape.search(line)]
+    assert not copies, copies
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < ROWS * D * 2
+    # The donated table is the scatter's output buffer: updated in place.
+    assert mem.alias_size_in_bytes == aliased

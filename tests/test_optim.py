@@ -81,7 +81,7 @@ def test_compressed_psum_error_feedback_unbiased():
 
     def one(axis_g, axis_r):
         # single-device psum via shard_map over a trivial mesh
-        from repro.launch.mesh import make_mesh
+        from repro.models.sharding import make_mesh
         mesh = make_mesh((1,), ("pod",))
         f = jax.shard_map(
             lambda gg, rr: compressed_psum(gg, rr, "pod", mode="int8"),
