@@ -1,0 +1,10 @@
+"""Puts the checkout's ``src`` and root on the path, so that the tests can
+import the benchmark (``bench``) and the program (``repro``)."""
+
+import sys
+from pathlib import Path
+
+ROOT = Path(__file__).resolve().parents[2]
+for p in (str(ROOT / "src"), str(ROOT)):
+    if p not in sys.path:
+        sys.path.insert(0, p)
