@@ -18,6 +18,25 @@ outputs. ``Request.tenant`` maps to the controller port — weighted
 arbitration + starvation cap is what protects a latency-SLO tenant from
 a bandwidth hog sharing the controller (tests/launch/test_serve.py).
 
+Every call is traced on the profiler's host timeline
+(``jax.profiler.TraceAnnotation``, the device planes' clock) and timed
+with host counters, always on; the spans cost nothing to speak of while
+no profiler runs, and add no device sync::
+
+    serve
+      serve.admit
+      serve.batch (batch)
+        serve.prefill (batch)           _prefill and the first argmax
+        serve.step (batch, step)        one per decode step
+          serve.read_tokens (batch)     the int(tok[i]) reads
+          serve.dispatch (batch)        _decode, argmax, astype, cur + 1
+      serve.model_memory                the modeled KV replay
+
+``batch`` counts the server's batches across calls, so every span of one
+lockstep batch carries the same number in a trace viewer. ``ServeStats``
+keeps the same phases' host seconds, and ``Request.token_times`` the
+host time at which each token reached the host.
+
 CPU-runnable demo: ``python -m repro.launch.serve --arch yi-34b --smoke``.
 """
 
@@ -31,6 +50,7 @@ from typing import Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs import get_arch
 from repro.core.config import MemoryControllerConfig, SchedulerConfig
@@ -51,6 +71,19 @@ class Request:
     arrival_cycle: int = 0
     tenant: int = 0             # controller port this request issues from
     output: Optional[List[int]] = None
+    #: ``time.perf_counter()`` when each output token reached the host
+    token_times: Optional[List[float]] = None
+
+
+@dataclasses.dataclass
+class BatchTimes:
+    """Host seconds of one lockstep batch, by phase."""
+
+    batch: int
+    prefill_s: float    # prefill dispatch until the first token is read
+    decode_s: float     # first token read until the last step dispatched
+    read_s: float       # in ``serve.read_tokens`` (waits on the device)
+    dispatch_s: float   # in ``serve.dispatch``
 
 
 @dataclasses.dataclass
@@ -58,8 +91,17 @@ class ServeStats:
     batches: int = 0
     requests: int = 0
     decode_steps: int = 0
-    prefill_tokens: int = 0
+    prefill_tokens: int = 0     # padded: rows x the batch's longest prompt
+    prompt_tokens: int = 0      # unpadded
+    decode_slots: int = 0       # rows x decode executions
+    useful_tokens: int = 0      # tokens served
     wall_s: float = 0.0
+    # host perf_counter seconds in the serve.read_tokens, serve.dispatch
+    # and serve.model_memory spans
+    read_s: float = 0.0
+    dispatch_s: float = 0.0
+    model_memory_s: float = 0.0
+    batch_times: List[BatchTimes] = dataclasses.field(default_factory=list)
     # modeled memory-system latency (FPGA cycles) of the KV access stream
     modeled_p50_cycles: float = 0.0
     modeled_p95_cycles: float = 0.0
@@ -104,6 +146,8 @@ class Server:
             lambda p, b, ml: self.lm.prefill(p, b, max_len=ml),
             static_argnums=(2,))
         self._decode = jax.jit(self.lm.decode_step)
+        #: batches run so far, across ``serve`` calls: the spans' ``batch``
+        self._batches = 0
 
     def admit(self, requests: List[Request]) -> List[List[Request]]:
         """Scheduler-policy batch formation over the arrival stream."""
@@ -123,21 +167,50 @@ class Server:
                             for r in batch])     # left-pad to align ends
         max_new = max(r.max_new_tokens for r in batch)
         max_len = S + max_new + 8
-        logits, cache, cur = self._prefill(
-            self.params, {"tokens": jnp.asarray(prompts)}, max_len)
-        stats.prefill_tokens += int(prompts.size)
+        bid = self._batches
+        self._batches += 1
         outs = [[] for _ in batch]
-        tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        for step in range(max_new):
-            for i, r in enumerate(batch):
-                if step < r.max_new_tokens:
-                    outs[i].append(int(tok[i]))
-            logits, cache = self._decode(self.params, tok, cache, cur)
-            cur = cur + 1
-            tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            stats.decode_steps += 1
-        for r, o in zip(batch, outs):
+        times = [[] for _ in batch]
+        read_s = dispatch_s = 0.0
+        with TraceAnnotation("serve.batch", batch=bid):
+            t_start = t_first = time.perf_counter()
+            with TraceAnnotation("serve.prefill", batch=bid):
+                logits, cache, cur = self._prefill(
+                    self.params, {"tokens": jnp.asarray(prompts)}, max_len)
+                tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            for step in range(max_new):
+                with TraceAnnotation("serve.step", batch=bid, step=step):
+                    t0 = time.perf_counter()
+                    with TraceAnnotation("serve.read_tokens", batch=bid):
+                        for i, r in enumerate(batch):
+                            if step < r.max_new_tokens:
+                                outs[i].append(int(tok[i]))
+                                times[i].append(time.perf_counter())
+                    t1 = time.perf_counter()
+                    with TraceAnnotation("serve.dispatch", batch=bid):
+                        logits, cache = self._decode(self.params, tok,
+                                                     cache, cur)
+                        cur = cur + 1
+                        tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                    t2 = time.perf_counter()
+                read_s += t1 - t0
+                dispatch_s += t2 - t1
+                if step == 0:
+                    t_first = t1
+            t_end = time.perf_counter()
+        for r, o, t in zip(batch, outs, times):
             r.output = o
+            r.token_times = t
+        stats.batch_times.append(BatchTimes(
+            batch=bid, prefill_s=t_first - t_start, decode_s=t_end - t_first,
+            read_s=read_s, dispatch_s=dispatch_s))
+        stats.prefill_tokens += int(prompts.size)
+        stats.prompt_tokens += sum(len(r.prompt) for r in batch)
+        stats.decode_steps += max_new
+        stats.decode_slots += len(batch) * max_new
+        stats.useful_tokens += sum(len(o) for o in outs)
+        stats.read_s += read_s
+        stats.dispatch_s += dispatch_s
         stats.batches += 1
         stats.requests += len(batch)
 
@@ -195,9 +268,14 @@ class Server:
         tenant missing its SLO" (arbitration starvation vs reorder
         slip vs refresh vs replay ...) — lands in the stats.
         """
-        pe, rows, rw, arr = self.kv_trace(batches)
-        if rows.size == 0:
-            return
+        t0 = time.perf_counter()
+        with TraceAnnotation("serve.model_memory"):
+            pe, rows, rw, arr = self.kv_trace(batches)
+            if rows.size:
+                self._replay(pe, rows, rw, arr, stats)
+        stats.model_memory_s += time.perf_counter() - t0
+
+    def _replay(self, pe, rows, rw, arr, stats: ServeStats) -> None:
         trace = None
         if self.slo_cycles is not None:
             from repro.core.telemetry import TraceRecorder
@@ -234,10 +312,12 @@ class Server:
     def serve(self, requests: List[Request]) -> ServeStats:
         stats = ServeStats()
         t0 = time.time()
-        batches = self.admit(requests)
-        for batch in batches:
-            self.run_batch(batch, stats)
-        self.model_memory(batches, stats)
+        with TraceAnnotation("serve"):
+            with TraceAnnotation("serve.admit"):
+                batches = self.admit(requests)
+            for batch in batches:
+                self.run_batch(batch, stats)
+            self.model_memory(batches, stats)
         stats.wall_s = time.time() - t0
         return stats
 
@@ -265,10 +345,31 @@ def main() -> None:
                     max_new_tokens=args.new_tokens,
                     arrival_cycle=i * 3)
             for i in range(args.requests)]
+    t0 = time.perf_counter()
     stats = server.serve(reqs)
     print(f"[serve] {stats.requests} requests in {stats.batches} batches, "
           f"{stats.decode_steps} decode steps, "
-          f"{stats.prefill_tokens} prefill tokens, {stats.wall_s:.1f}s")
+          f"{stats.prefill_tokens} prefill tokens "
+          f"({100 * stats.prompt_tokens / max(1, stats.prefill_tokens):.1f}% "
+          f"prompt, the rest padding), {stats.wall_s:.1f}s")
+    ttft = [1e3 * (r.token_times[0] - t0) for r in reqs if r.token_times]
+    tpot = [1e3 * float(np.mean(np.diff(r.token_times)))
+            for r in reqs if len(r.token_times) > 1]
+    for name, ms in (("TTFT", ttft), ("TPOT", tpot)):
+        if ms:
+            p50, p95 = np.percentile(ms, [50, 95])
+            print(f"[serve] {name} p50={p50:.1f}ms p95={p95:.1f}ms")
+    print(f"[serve] decode slot use "
+          f"{100 * stats.useful_tokens / max(1, stats.decode_slots):.1f}% "
+          f"({stats.useful_tokens}/{stats.decode_slots}); host token reads "
+          f"{stats.read_s:.3f}s, dispatch {stats.dispatch_s:.3f}s, "
+          f"modeled-memory replay {stats.model_memory_s:.3f}s")
+    if stats.batch_times:
+        slow = max(stats.batch_times, key=lambda b: b.prefill_s + b.decode_s)
+        print(f"[serve] slowest batch {slow.batch}: prefill to first token "
+              f"{slow.prefill_s:.3f}s, decode loop {slow.decode_s:.3f}s "
+              f"(token reads {slow.read_s:.3f}s, "
+              f"dispatch {slow.dispatch_s:.3f}s)")
     print(f"[serve] modeled KV latency (FPGA cycles): "
           f"p50={stats.modeled_p50_cycles:.1f} "
           f"p95={stats.modeled_p95_cycles:.1f} "

@@ -277,17 +277,18 @@ def moe_ffn(p, x, cfg: ArchConfig, rules: Rules, mesh, *,
         # expert (same-address consistency), so slots equal the
         # sequential-arrival (cumsum) semantics without the O(n·E)
         # prefix scan.
-        order = jnp.argsort(e_grp, axis=-1, stable=True)
-        e_sorted = jnp.take_along_axis(e_grp, order, axis=-1)
-        run_start = jax.vmap(
-            lambda es: jnp.searchsorted(es, jnp.arange(m.num_experts)))(
-            e_sorted)                            # (G, E)
-        pos_sorted = (jnp.arange(na)[None, :]
-                      - jnp.take_along_axis(run_start, e_sorted, axis=-1)
-                      ).astype(jnp.int32)
-        pos_in_e = jnp.zeros((G, na), jnp.int32)
-        pos_in_e = jax.vmap(lambda z, o, v: z.at[o].set(v))(
-            pos_in_e, order, pos_sorted)
+        with jax.named_scope("moe_dispatch"):
+            order = jnp.argsort(e_grp, axis=-1, stable=True)
+            e_sorted = jnp.take_along_axis(e_grp, order, axis=-1)
+            run_start = jax.vmap(
+                lambda es: jnp.searchsorted(es, jnp.arange(m.num_experts)))(
+                e_sorted)                            # (G, E)
+            pos_sorted = (jnp.arange(na)[None, :]
+                          - jnp.take_along_axis(run_start, e_sorted, axis=-1)
+                          ).astype(jnp.int32)
+            pos_in_e = jnp.zeros((G, na), jnp.int32)
+            pos_in_e = jax.vmap(lambda z, o, v: z.at[o].set(v))(
+                pos_in_e, order, pos_sorted)
     else:                         # "cumsum": GShard-style naive dispatch
         onehot = jax.nn.one_hot(e_grp, m.num_experts, dtype=jnp.int32)
         pos_in_e = (jnp.cumsum(onehot, axis=1) * onehot).sum(-1) - 1

@@ -194,6 +194,7 @@ def _capture_embed(op: str, table, tokens, rw: int) -> None:
     cap.record(op, name, n_rows, row_bytes, tokens, rw=rw, pe_id=pe)
 
 
+@jax.named_scope("mc_embed")
 def mc_embed(table: jnp.ndarray, tokens: jnp.ndarray,
              mc: MemoryControllerConfig) -> jnp.ndarray:
     """Embedding gather through the memory controller's scheduler.
@@ -221,6 +222,7 @@ def mc_embed(table: jnp.ndarray, tokens: jnp.ndarray,
     return jnp.take_along_axis(gathered, inv[..., None], axis=-2)
 
 
+@jax.named_scope("mc_scatter")
 def mc_scatter(table: jnp.ndarray, tokens: jnp.ndarray,
                values: jnp.ndarray, mc: MemoryControllerConfig,
                *, mode: str = "add") -> jnp.ndarray:
@@ -237,6 +239,7 @@ def mc_scatter(table: jnp.ndarray, tokens: jnp.ndarray,
     return MemoryController(mc).scatter(table, tokens, values, mode=mode)
 
 
+@jax.named_scope("mc_kv_append")
 def mc_kv_append(buf: jnp.ndarray, new: jnp.ndarray, slot,
                  mc: MemoryControllerConfig, axis: int = 1) -> jnp.ndarray:
     """One decode-step KV append — the controller's bulk-write request

@@ -381,6 +381,7 @@ class LM:
                     ssm=r.spec("layers", "batch", "heads", None, None))}
         return specs
 
+    @jax.named_scope("prefill")
     def prefill(self, params, batch, max_len: int):
         """Full-context forward; returns (last_logits, cache, cur_len)."""
         cfg = self.cfg
@@ -405,6 +406,7 @@ class LM:
         logits = (xn @ params["lm_head"])[:, :cfg.vocab_size]
         return logits, cache, jnp.asarray(S, jnp.int32)
 
+    @jax.named_scope("decode_step")
     def decode_step(self, params, token: jnp.ndarray, cache,
                     cur_len: jnp.ndarray):
         """One serve step: embed token (B,), walk layers, update cache."""

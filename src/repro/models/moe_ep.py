@@ -118,13 +118,14 @@ def moe_ffn_ep(p, x, cfg: ArchConfig, mesh, *, no_drop: bool = False):
             if c_send >= 64:
                 c_send = -(-c_send // 128) * 128
             c_send = min(n, c_send)
-        owner = e_flat // e_loc                       # destination shard
-        order = jnp.argsort(owner, stable=True)       # bitonic analogue
-        owner_s = jnp.take(owner, order)
-        run_start = jnp.searchsorted(owner_s, jnp.arange(tp))
-        pos = (jnp.arange(n) - jnp.take(run_start, owner_s)
-               ).astype(jnp.int32)
-        slot = jnp.where(pos < c_send, pos, c_send)   # drop slot
+        with jax.named_scope("moe_dispatch"):
+            owner = e_flat // e_loc                   # destination shard
+            order = jnp.argsort(owner, stable=True)   # bitonic analogue
+            owner_s = jnp.take(owner, order)
+            run_start = jnp.searchsorted(owner_s, jnp.arange(tp))
+            pos = (jnp.arange(n) - jnp.take(run_start, owner_s)
+                   ).astype(jnp.int32)
+            slot = jnp.where(pos < c_send, pos, c_send)   # drop slot
 
         tok_of = jnp.take(jnp.repeat(jnp.arange(t_loc), m.top_k), order)
         eid_of = jnp.take(e_flat % e_loc, order)      # local expert id

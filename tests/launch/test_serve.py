@@ -13,6 +13,8 @@ serve run reports modeled memory sojourns per tenant. These tests pin:
 Model forward passes are real (smoke-sized) jitted JAX; keep sizes tiny.
 """
 
+import re
+
 import numpy as np
 import pytest
 
@@ -93,3 +95,77 @@ def test_serve_outputs_and_admission_unchanged():
     server.serve(reqs)
     for r in reqs:
         assert r.output is not None and len(r.output) == r.max_new_tokens
+
+
+def test_serve_counters_and_token_times():
+    """Host counters of one ``serve`` call against hand counts: rids 0
+    and 1 (prompts 5 and 7, 3 and 2 new tokens) form one batch, rid 2
+    (prompt 4, 4 new tokens) arrives after the timeout and forms
+    another."""
+    server = Server("h2o-danube-1.8b", smoke=True)
+    reqs = [Request(0, np.arange(5, dtype=np.int32), 3, 0),
+            Request(1, np.arange(7, dtype=np.int32) + 3, 2, 1),
+            Request(2, np.arange(4, dtype=np.int32) + 9, 4, 200)]
+    stats = server.serve(reqs)
+    assert stats.batches == 2 and stats.requests == 3
+    assert stats.prompt_tokens == 5 + 7 + 4
+    assert stats.prefill_tokens == 2 * 7 + 1 * 4      # padded
+    assert stats.decode_steps == 3 + 4
+    assert stats.decode_slots == 2 * 3 + 1 * 4
+    assert stats.useful_tokens == 3 + 2 + 4
+    assert [b.batch for b in stats.batch_times] == [0, 1]
+    for name in ("read_s", "dispatch_s"):
+        assert getattr(stats, name) == pytest.approx(
+            sum(getattr(b, name) for b in stats.batch_times))
+        assert getattr(stats, name) > 0
+    assert stats.model_memory_s > 0
+    for b in stats.batch_times:
+        assert b.prefill_s > 0 and b.decode_s > 0
+        assert b.read_s + b.dispatch_s <= b.prefill_s + b.decode_s
+    for r in reqs:
+        assert len(r.token_times) == len(r.output) == r.max_new_tokens
+        assert all(np.diff(r.token_times) > 0)
+    # rows of one batch reach the host in the same step, in row order
+    assert reqs[0].token_times[0] < reqs[1].token_times[0] \
+        < reqs[0].token_times[1]
+    # the batch counter runs on across calls
+    server.serve([Request(**{**reqs[2].__dict__, "output": None})])
+    assert server._batches == 3
+
+
+def test_decode_step_carries_the_controller_scopes():
+    """The compiled decode step names its memory-layer operations: the
+    scope paths a device trace files their time under."""
+    import jax
+    import jax.numpy as jnp
+    server = Server("h2o-danube-1.8b", smoke=True)
+    tokens = jnp.zeros((2, 8), jnp.int32)
+    _, cache, cur = server._prefill(server.params, {"tokens": tokens}, 16)
+    hlo = server._decode.lower(server.params, jnp.zeros((2,), jnp.int32),
+                               cache, cur).compile().as_text()
+    paths = set(re.findall(r'op_name="([^"]*)"', hlo))
+    for scope in ("mc_embed", "mc_kv_append"):
+        assert any(p.startswith("jit(decode_step)/decode_step/")
+                   and f"/{scope}/" in p for p in paths), scope
+    assert jax.jit(server.lm.decode_step).__name__ == "decode_step"
+
+
+def test_main_prints_the_counters(monkeypatch, capsys):
+    """``python -m repro.launch.serve`` shows the operator the counters:
+    the prompt share of the padded prefill, TTFT and TPOT, decode slot
+    use, the host phases and the slowest batch's."""
+    import repro.launch.serve as serve_mod
+    monkeypatch.setattr(serve_mod, "use_compile_cache", lambda: "")
+    monkeypatch.setattr("sys.argv", [
+        "serve", "--arch", "h2o-danube-1.8b", "--smoke", "--requests", "3",
+        "--prompt-len", "5", "--new-tokens", "3"])
+    serve_mod.main()
+    out = capsys.readouterr().out
+    # one batch of 3 unpadded prompts, every row stepping all 3 times
+    assert "15 prefill tokens (100.0% prompt, the rest padding)" in out
+    assert re.search(r"TTFT p50=[\d.]+ms p95=[\d.]+ms", out)
+    assert re.search(r"TPOT p50=[\d.]+ms p95=[\d.]+ms", out)
+    assert "decode slot use 100.0% (9/9)" in out
+    assert re.search(r"slowest batch 0: prefill to first token [\d.]+s, "
+                     r"decode loop [\d.]+s \(token reads [\d.]+s, "
+                     r"dispatch [\d.]+s\)", out)
