@@ -145,7 +145,9 @@ class Server:
         self._prefill = jax.jit(
             lambda p, b, ml: self.lm.prefill(p, b, max_len=ml),
             static_argnums=(2,))
-        self._decode = jax.jit(self.lm.decode_step)
+        # The cache is donated: each step updates it in place, so a caller
+        # must not touch a cache after passing it in.
+        self._decode = jax.jit(self.lm.decode_step, donate_argnums=(2,))
         #: batches run so far, across ``serve`` calls: the spans' ``batch``
         self._batches = 0
 

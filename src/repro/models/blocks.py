@@ -115,18 +115,23 @@ def attn_prefill_cache(kv: AttnCache, cfg: ArchConfig, seq_len: int,
     return AttnCache(k, v)
 
 
-def attn_decode(p, x, cache, cur_len: jnp.ndarray,
+def attn_decode(p, x, cache, layer, cur_len: jnp.ndarray,
                 cfg: ArchConfig, rules: Rules, mesh):
-    """One-token attention against the cache; returns (out, new_cache).
+    """One-token attention of layer ``layer``; returns (out, new_cache).
 
-    ``cur_len`` is the number of tokens already in the cache; the new token
-    occupies position ``cur_len``. Accepts either a plain ``AttnCache`` or
-    a ``QuantAttnCache`` (int8 storage, dequantized at read — half the
-    HBM traffic per step).
+    ``cache`` is the stacked cache of every layer (a leading layers axis).
+    The new token's K/V row is written into it first, in place
+    (``mc_kv_append``), and then the layer's own slice is read back for
+    attention, so a step never rewrites the whole cache. ``cur_len`` is
+    the number of
+    tokens already in the cache; the new token occupies position
+    ``cur_len``. Accepts either a plain ``AttnCache`` or a
+    ``QuantAttnCache`` (int8 storage, dequantized at read — half the HBM
+    traffic per step).
     """
     B, _ = x.shape
     h, kv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-    C = cache.k.shape[1]
+    C = cache.k.shape[2]
     w = cfg.attn_window
     xn = layers.rms_norm(x, p["ln"])
     pos = jnp.full((B, 1), cur_len, jnp.int32)
@@ -137,9 +142,12 @@ def attn_decode(p, x, cache, cur_len: jnp.ndarray,
     quant = isinstance(cache, QuantAttnCache)
     slot = cur_len % C if w is not None else cur_len
 
-    def dus(buf, new, axis=1):
+    def dus(buf, new):
         # KV append = the controller's bulk-write request class (fig7w).
-        return layers.mc_kv_append(buf, new, slot, cfg.mc, axis=axis)
+        return layers.mc_kv_append(buf, new, slot, cfg.mc, layer=layer)
+
+    def own(buf):
+        return jax.lax.dynamic_index_in_dim(buf, layer, 0, keepdims=False)
 
     if quant:
         kq, ks = quantize_kv(k)
@@ -147,15 +155,16 @@ def attn_decode(p, x, cache, cur_len: jnp.ndarray,
         new_cache = QuantAttnCache(
             k=dus(cache.k, kq), v=dus(cache.v, vq),
             k_scale=dus(cache.k_scale, ks), v_scale=dus(cache.v_scale, vs))
-        full_k = dequantize_kv(new_cache.k, new_cache.k_scale, x.dtype)
-        full_v = dequantize_kv(new_cache.v, new_cache.v_scale, x.dtype)
+        full_k = dequantize_kv(own(new_cache.k), own(new_cache.k_scale),
+                               x.dtype)
+        full_v = dequantize_kv(own(new_cache.v), own(new_cache.v_scale),
+                               x.dtype)
     else:
-        new_k = shard(dus(cache.k, k), rules, "batch", "kv_seq", None,
-                      None, mesh=mesh)
-        new_v = shard(dus(cache.v, v), rules, "batch", "kv_seq", None,
-                      None, mesh=mesh)
-        new_cache = AttnCache(new_k, new_v)
-        full_k, full_v = new_k, new_v
+        new_cache = AttnCache(*(
+            shard(dus(buf, new), rules, "layers", "batch", "kv_seq", None,
+                  None, mesh=mesh)
+            for buf, new in ((cache.k, k), (cache.v, v))))
+        full_k, full_v = own(new_cache.k), own(new_cache.v)
 
     n_valid = jnp.minimum(cur_len + 1, C)
     valid = jnp.broadcast_to(jnp.arange(C) < n_valid, (B, C))
@@ -437,10 +446,17 @@ def mamba_forward(p, x, cfg: ArchConfig, rules: Rules, mesh
     return shard(out, rules, "batch", "seq", "embed", mesh=mesh), cache
 
 
-def mamba_decode(p, x, cache: MambaCache, cfg: ArchConfig, rules: Rules,
-                 mesh) -> Tuple[jnp.ndarray, MambaCache]:
-    """O(1) recurrent step. x: (B, D)."""
+def mamba_decode(p, x, stacked: MambaCache, layer, cfg: ArchConfig,
+                 rules: Rules, mesh) -> Tuple[jnp.ndarray, MambaCache]:
+    """O(1) recurrent step of layer ``layer``. x: (B, D).
+
+    ``stacked`` holds every layer's state (a leading layers axis); the
+    layer's whole state is read out and its new state written back into
+    the stacked buffers."""
     B, D = x.shape
+    cache = jax.tree.map(
+        lambda t: jax.lax.dynamic_index_in_dim(t, layer, 0, keepdims=False),
+        stacked)
     d_in, H, P, N = mamba_dims(cfg)
     cap = capture_mod.active_capture()
     if cap is not None and capture_mod.is_concrete(x):
@@ -473,4 +489,7 @@ def mamba_decode(p, x, cache: MambaCache, cfg: ArchConfig, rules: Rules,
          * jax.nn.silu(z[:, 0].astype(jnp.float32)))
     y = layers.rms_norm(y.astype(x.dtype), p["gated_ln"])
     out = y @ p["wo"]
-    return out, MambaCache(conv_x, conv_b, conv_c, h_new)
+    return out, jax.tree.map(
+        lambda buf, new: jax.lax.dynamic_update_index_in_dim(buf, new,
+                                                             layer, 0),
+        stacked, MambaCache(conv_x, conv_b, conv_c, h_new))

@@ -239,28 +239,67 @@ def mc_scatter(table: jnp.ndarray, tokens: jnp.ndarray,
     return MemoryController(mc).scatter(table, tokens, values, mode=mode)
 
 
+#: Slots an append rewrites around the new one: a TPU tile's lane width.
+#: Where the head dim is not a multiple of 128 (80 in h2o-danube-1.8b), a
+#: TPU keeps the cache with the slot axis minor, so one slot is one lane
+#: of its tiles. A one-slot update then makes the compiler lay the whole
+#: cache out anew around the decode loop (a copy of it in and out of
+#: every step); a block as wide as a tile keeps the layout, in place.
+KV_WRITE_SLOTS = 128
+
+
 @jax.named_scope("mc_kv_append")
 def mc_kv_append(buf: jnp.ndarray, new: jnp.ndarray, slot,
-                 mc: MemoryControllerConfig, axis: int = 1) -> jnp.ndarray:
+                 mc: MemoryControllerConfig, axis: int = 1,
+                 layer=None) -> jnp.ndarray:
     """One decode-step KV append — the controller's bulk-write request
     class.
+
+    ``new`` is one row (size 1 on ``axis``), stored at ``slot``, clamped
+    into the buffer like ``lax.dynamic_update_slice``. With ``layer``,
+    ``buf`` is the stacked cache of every layer (a leading layers axis)
+    and the row lands in ``buf[layer]``; ``axis`` counts within that
+    layer's slice. The row is written as the ``KV_WRITE_SLOTS``-slot block
+    that holds it, every other slot of the block unchanged, so a step
+    moves one block per layer, in place where ``buf`` is a loop carry or
+    a donated argument.
 
     A cache row is a contiguous page, so the append is classified as a
     bulk/streaming write (cache-bypassing), not an irregular scatter;
     its DRAM cost is what ``benchmarks/fig7_write_workloads.py`` models.
-    The data-plane transport is the default dynamic-update for every
-    engine setting; ``mc`` marks the request class, which the capture
-    hook reports as ``kv_append`` bulk-write records (op label suffixed
-    ``_dma`` when the config's DMA engine owns the stream) — never
-    affecting stored values.
+    The data plane is the same for every engine setting; ``mc`` marks
+    the request class, which the capture hook reports as ``kv_append``
+    bulk-write records (op label suffixed ``_dma`` when the config's DMA
+    engine owns the stream) — never affecting stored values.
     """
+    if new.shape[axis] != 1:
+        raise ValueError(f"one row per append, got {new.shape[axis]}")
+    starts = [0] * buf.ndim
+    sizes = list(buf.shape)
+    if layer is not None:
+        new = new[None]
+        axis += 1
+        starts[0], sizes[0] = layer, 1
+    pages = int(buf.shape[axis])
     cap = capture_mod.active_capture()
     if cap is not None:
-        pages = int(buf.shape[axis])
-        n_new = int(new.shape[axis])
-        page_bytes = (int(np.prod(new.shape)) // max(1, n_new)
+        page_bytes = (int(np.prod(new.shape))
                       * int(np.dtype(new.dtype).itemsize))
         op = "kv_append_dma" if mc.dma.enabled else "kv_append"
         cap.record_slice(op, f"kv:{pages}x{page_bytes}", pages, page_bytes,
-                         slot, n_new, rw=1)
-    return jax.lax.dynamic_update_slice_in_dim(buf, new, slot, axis)
+                         slot, 1, rw=1)
+    slot = jnp.clip(slot, 0, pages - 1)
+    width = min(pages, KV_WRITE_SLOTS)
+    base = slot // width * width
+    if pages % width:       # the last block ends at the buffer's end
+        base = jnp.minimum(base, pages - width)
+    starts[axis], sizes[axis] = base, width
+    # No start is negative. Without the wrap-around of negative starts the
+    # TPU compiler can tell the block is tile-aligned, and fuses the read,
+    # the select and the write into one in-place update.
+    block = jax.lax.dynamic_slice(buf, starts, sizes,
+                                  allow_negative_indices=False)
+    hit = (jnp.arange(width) == slot - base).reshape(
+        [width if d == axis else 1 for d in range(buf.ndim)])
+    return jax.lax.dynamic_update_slice(buf, jnp.where(hit, new, block),
+                                        starts, allow_negative_indices=False)

@@ -149,10 +149,12 @@ class LM:
 
     # ---------------- block walker ------------------------------------------
     def _run_block(self, bp, x, layer_pos: int, positions,
-                   mode: str, cache=None, cur_len=None):
+                   mode: str, cache=None, cur_len=None, layer=None):
         """One (mixer, ffn) sub-block with residuals.
 
-        Returns (x, aux_losses, new_cache)."""
+        In decode, ``cache`` is this position's stacked cache and
+        ``layer`` the group it belongs to. Returns (x, aux_losses,
+        new_cache)."""
         cfg, rules, mesh = self.cfg, self.rules, self.mesh
         aux = {"load_balance": jnp.zeros((), jnp.float32),
                "router_z": jnp.zeros((), jnp.float32)}
@@ -160,7 +162,8 @@ class LM:
         if "attn" in bp:
             if mode == "decode":
                 out, kv = blocks.attn_decode(bp["attn"], x, cache["attn"],
-                                             cur_len, cfg, rules, mesh)
+                                             layer, cur_len, cfg, rules,
+                                             mesh)
             else:
                 out, kv = blocks.attn_forward(bp["attn"], x, cfg, rules,
                                               mesh, positions)
@@ -169,7 +172,7 @@ class LM:
         elif "mamba" in bp:
             if mode == "decode":
                 out, mc = blocks.mamba_decode(bp["mamba"], x, cache["mamba"],
-                                              cfg, rules, mesh)
+                                              layer, cfg, rules, mesh)
             else:
                 out, mc = blocks.mamba_forward(bp["mamba"], x, cfg, rules,
                                                mesh)
@@ -198,18 +201,47 @@ class LM:
 
     def _scan_layers(self, params, x, positions, mode: str,
                      cache=None, cur_len=None):
-        """Scan the stacked layer groups. Returns (x, aux, caches)."""
+        """Scan the stacked layer groups. Returns (x, aux, caches).
+
+        Train and prefill return each layer's new cache as the scan's
+        outputs. Decode carries the stacked serve ``cache`` beside ``x``
+        instead: each layer writes its new KV row (or Mamba state) into it
+        in place and reads its own slice back, so a step does not rebuild
+        the whole cache; its aux is None."""
         cfg = self.cfg
         period = cfg.scan_period
+        groups = jax.tree.leaves(params["layers"])[0].shape[0]
 
-        def group_fn(x, xs):
-            gp, gcache = xs
+        def group_params(g):
+            return jax.tree.map(lambda t: t[g], params["layers"])
+
+        if mode == "decode":
+            def decode_fn(carry, xs):
+                x, cache = carry
+                gp, g = xs
+                cache = dict(cache)
+                for pos in range(period):
+                    key = f"pos{pos}"
+                    x, _, cache[key] = self._run_block(
+                        gp[key], x, pos, None, mode, cache=cache[key],
+                        cur_len=cur_len, layer=g)
+                return (x, cache), None
+
+            carry = (x, cache)
+            if cfg.scan_layers:
+                carry, _ = jax.lax.scan(
+                    decode_fn, carry, (params["layers"], jnp.arange(groups)))
+            else:
+                for g in range(groups):
+                    carry, _ = decode_fn(carry, (group_params(g), g))
+            x, cache = carry
+            return x, None, cache
+
+        def group_fn(x, gp):
             auxes, ncaches = [], {}
             for pos in range(period):
-                c = None if gcache is None else gcache.get(f"pos{pos}")
-                x, aux, nc = self._run_block(
-                    gp[f"pos{pos}"], x, pos, positions, mode,
-                    cache=c, cur_len=cur_len)
+                x, aux, nc = self._run_block(gp[f"pos{pos}"], x, pos,
+                                             positions, mode)
                 auxes.append(aux)
                 if mode != "train":       # train never materializes caches
                     ncaches[f"pos{pos}"] = nc
@@ -223,18 +255,15 @@ class LM:
                       else jax.checkpoint_policies.nothing_saveable)
             fn = jax.checkpoint(group_fn, policy=policy)
 
-        xs = (params["layers"], cache)
         if cfg.scan_layers:
-            x, (aux, caches) = jax.lax.scan(fn, x, xs)
+            x, (aux, caches) = jax.lax.scan(fn, x, params["layers"])
             aux = jax.tree.map(jnp.sum, aux)
             return x, aux, caches
         # Unrolled path (dry-run cost extrapolation / tiny models): walk the
         # stacked groups in Python, then restack outputs like scan would.
-        groups = jax.tree.leaves(params["layers"])[0].shape[0]
         auxes, caches_list = [], []
         for g in range(groups):
-            xs_g = jax.tree.map(lambda t: t[g], xs)
-            x, (aux, ncache) = fn(x, xs_g)
+            x, (aux, ncache) = fn(x, group_params(g))
             auxes.append(aux)
             caches_list.append(ncache)
         aux = jax.tree.map(lambda *a: jnp.sum(jnp.stack(a)), *auxes)

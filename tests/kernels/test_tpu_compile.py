@@ -155,3 +155,40 @@ def test_row_kernel_reads_table_in_place(program, aliased, one_chip):
     assert mem.temp_size_in_bytes < ROWS * D * 2
     # The donated table is the scatter's output buffer: updated in place.
     assert mem.alias_size_in_bytes == aliased
+
+
+def test_served_decode_updates_the_cache_in_place(one_chip, monkeypatch):
+    """``Server``'s decode at h2o-danube-1.8b widths (batch 8, 4096 KV
+    slots) takes its donated cache as its output buffer and writes each
+    layer's new row there: no whole layer's K or V is written into the
+    stack, and the stack is never copied."""
+    from repro.launch.serve import Server
+    from repro.models.lm import LM
+    monkeypatch.setattr(LM, "init", lambda self, key: self.abstract_params())
+    server = Server("h2o-danube-1.8b")
+
+    def put(t):
+        return _spec(t.shape, t.dtype, one_chip)
+
+    cache = jax.tree.map(put, server.lm.init_cache(8, 4096, abstract=True))
+    stack = (24, 8, 4096, 8, 80)
+    assert {leaf.shape for leaf in jax.tree.leaves(cache)} == {stack}
+    compiled = server._decode.lower(
+        jax.tree.map(put, server.params), _spec((8,), jnp.int32, one_chip),
+        cache, _spec((), jnp.int32, one_chip)).compile()
+    cache_bytes = sum(leaf.size * leaf.dtype.itemsize
+                      for leaf in jax.tree.leaves(cache))
+    assert cache_bytes == 2_013_265_920
+    assert compiled.memory_analysis().alias_size_in_bytes == cache_bytes
+
+    text = compiled.as_text()
+    shapes = dict(re.findall(r"%([\w.\-]+) = \w+\[([\d,]*)\]", text))
+    stacked = ",".join(map(str, stack))
+    updates = re.findall(rf"= \w+\[{stacked}\]\S* dynamic-update-slice\("
+                         r"%[\w.\-]+, %([\w.\-]+)", text)
+    assert updates
+    for name in updates:
+        slots = int(shapes[name].split(",")[-3])
+        assert slots < 4096, (name, shapes[name])
+    copies = re.findall(rf"= \w+\[{stacked}\]\S* copy\(", text)
+    assert not copies, copies

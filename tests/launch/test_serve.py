@@ -97,6 +97,30 @@ def test_serve_outputs_and_admission_unchanged():
         assert r.output is not None and len(r.output) == r.max_new_tokens
 
 
+def test_donated_decode_serves_the_same_outputs():
+    """``Server._decode`` donates its cache, so each step updates it in
+    place: the served tokens are those of an undonated decode, and a
+    cache passed in is gone afterwards."""
+    import jax
+    import jax.numpy as jnp
+    rng = np.random.default_rng(2)
+    reqs = _requests(rng, n_victim=2, n_hog=2, hog_new=12)
+    server = Server("h2o-danube-1.8b", smoke=True,
+                    mem=MemoryControllerConfig(num_pes=2))
+    _, cache, cur = server._prefill(
+        server.params, {"tokens": jnp.zeros((2, 8), jnp.int32)}, 16)
+    server._decode(server.params, jnp.zeros((2,), jnp.int32), cache, cur)
+    assert all(leaf.is_deleted() for leaf in jax.tree.leaves(cache))
+
+    server.serve(reqs)
+    want = [Request(**{**r.__dict__, "output": None}) for r in reqs]
+    server._decode = jax.jit(server.lm.decode_step)
+    server.serve(want)
+    for r, w in zip(reqs, want):
+        assert len(r.output) == r.max_new_tokens
+        assert r.output == w.output, r.rid
+
+
 def test_serve_counters_and_token_times():
     """Host counters of one ``serve`` call against hand counts: rids 0
     and 1 (prompts 5 and 7, 3 and 2 new tokens) form one batch, rid 2
