@@ -95,6 +95,10 @@ class ServeStats:
     prompt_tokens: int = 0      # unpadded
     decode_slots: int = 0       # rows x decode executions
     useful_tokens: int = 0      # tokens served
+    #: bytes of decode state the last batch carried (its cache pytree's
+    #: shapes): the KV cache of an attention model, the SSM and
+    #: convolution state of a Mamba-2 one
+    state_bytes: int = 0
     wall_s: float = 0.0
     # host perf_counter seconds in the serve.read_tokens, serve.dispatch
     # and serve.model_memory spans
@@ -180,6 +184,8 @@ class Server:
                 logits, cache, cur = self._prefill(
                     self.params, {"tokens": jnp.asarray(prompts)}, max_len)
                 tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            stats.state_bytes = sum(leaf.size * leaf.dtype.itemsize
+                                    for leaf in jax.tree.leaves(cache))
             for step in range(max_new):
                 with TraceAnnotation("serve.step", batch=bid, step=step):
                     t0 = time.perf_counter()
@@ -354,6 +360,8 @@ def main() -> None:
           f"{stats.prefill_tokens} prefill tokens "
           f"({100 * stats.prompt_tokens / max(1, stats.prefill_tokens):.1f}% "
           f"prompt, the rest padding), {stats.wall_s:.1f}s")
+    print(f"[serve] decode state of the last batch: {stats.state_bytes} "
+          f"bytes")
     ttft = [1e3 * (r.token_times[0] - t0) for r in reqs if r.token_times]
     tpot = [1e3 * float(np.mean(np.diff(r.token_times)))
             for r in reqs if len(r.token_times) > 1]

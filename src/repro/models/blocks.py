@@ -387,9 +387,10 @@ def mamba_forward(p, x, cfg: ArchConfig, rules: Rules, mesh
     L = min(cfg.ssm.chunk, S)
 
     z, xin, b, c, dt = _mamba_project(p, x, cfg)
-    xin, conv_x = _causal_conv(xin, p["conv_x"])
-    b, conv_b = _causal_conv(b, p["conv_b"])
-    c, conv_c = _causal_conv(c, p["conv_c"])
+    with jax.named_scope("mamba_conv"):
+        xin, conv_x = _causal_conv(xin, p["conv_x"])
+        b, conv_b = _causal_conv(b, p["conv_b"])
+        c, conv_c = _causal_conv(c, p["conv_c"])
     a = -jnp.exp(p["a_log"])                               # (H,) negative
 
     # Pad to a chunk multiple. Padded positions get dt=0, which makes them
@@ -433,7 +434,8 @@ def mamba_forward(p, x, cfg: ArchConfig, rules: Rules, mesh
     h0 = jnp.zeros((B, H, P, N), jnp.float32)
     xs = (xh.transpose(1, 0, 2, 3, 4), dtc.transpose(1, 0, 2, 3),
           bc_.transpose(1, 0, 2, 3), cc_.transpose(1, 0, 2, 3))
-    h_final, ys = jax.lax.scan(chunk_step, h0, xs)
+    with jax.named_scope("ssd_chunk_scan"):
+        h_final, ys = jax.lax.scan(chunk_step, h0, xs)
     y = ys.transpose(1, 0, 2, 3, 4).reshape(B, Sp, H, P)[:, :S]
     y = y + xh.reshape(B, Sp, H, P)[:, :S] * p["d_skip"][None, None, :, None]
     y = y.reshape(B, S, d_in)
@@ -454,9 +456,11 @@ def mamba_decode(p, x, stacked: MambaCache, layer, cfg: ArchConfig,
     layer's whole state is read out and its new state written back into
     the stacked buffers."""
     B, D = x.shape
-    cache = jax.tree.map(
-        lambda t: jax.lax.dynamic_index_in_dim(t, layer, 0, keepdims=False),
-        stacked)
+    with jax.named_scope("ssm_state_step"):
+        cache = jax.tree.map(
+            lambda t: jax.lax.dynamic_index_in_dim(t, layer, 0,
+                                                   keepdims=False),
+            stacked)
     d_in, H, P, N = mamba_dims(cfg)
     cap = capture_mod.active_capture()
     if cap is not None and capture_mod.is_concrete(x):
@@ -470,9 +474,10 @@ def mamba_decode(p, x, stacked: MambaCache, layer, cfg: ArchConfig,
                    page_bytes, np.tile(np.arange(H, dtype=np.int64), B),
                    rw=1, pe_id=np.repeat(np.arange(B, dtype=np.int64), H))
     z, xin, b, c, dt = _mamba_project(p, x[:, None, :], cfg)
-    xin, conv_x = _causal_conv(xin, p["conv_x"], cache.conv_x)
-    b, conv_b = _causal_conv(b, p["conv_b"], cache.conv_b)
-    c, conv_c = _causal_conv(c, p["conv_c"], cache.conv_c)
+    with jax.named_scope("mamba_conv"):
+        xin, conv_x = _causal_conv(xin, p["conv_x"], cache.conv_x)
+        b, conv_b = _causal_conv(b, p["conv_b"], cache.conv_b)
+        c, conv_c = _causal_conv(c, p["conv_c"], cache.conv_c)
 
     xh = xin[:, 0].reshape(B, H, P).astype(jnp.float32)
     dt1 = dt[:, 0]                                         # (B, H)
@@ -480,16 +485,19 @@ def mamba_decode(p, x, stacked: MambaCache, layer, cfg: ArchConfig,
     c1 = c[:, 0].astype(jnp.float32)
     a = -jnp.exp(p["a_log"])
 
-    da = jnp.exp(dt1 * a)                                  # (B, H)
-    h_new = (cache.ssm * da[:, :, None, None]
-             + jnp.einsum("bh,bhp,bn->bhpn", dt1, xh, b1))
-    y = jnp.einsum("bhpn,bn->bhp", h_new, c1)
+    with jax.named_scope("ssm_state_step"):
+        da = jnp.exp(dt1 * a)                              # (B, H)
+        h_new = (cache.ssm * da[:, :, None, None]
+                 + jnp.einsum("bh,bhp,bn->bhpn", dt1, xh, b1))
+        y = jnp.einsum("bhpn,bn->bhp", h_new, c1)
     y = y + xh * p["d_skip"][None, :, None]
     y = (y.reshape(B, d_in)
          * jax.nn.silu(z[:, 0].astype(jnp.float32)))
     y = layers.rms_norm(y.astype(x.dtype), p["gated_ln"])
     out = y @ p["wo"]
-    return out, jax.tree.map(
-        lambda buf, new: jax.lax.dynamic_update_index_in_dim(buf, new,
-                                                             layer, 0),
-        stacked, MambaCache(conv_x, conv_b, conv_c, h_new))
+    with jax.named_scope("ssm_state_step"):
+        stacked = jax.tree.map(
+            lambda buf, new: jax.lax.dynamic_update_index_in_dim(
+                buf, new, layer, 0),
+            stacked, MambaCache(conv_x, conv_b, conv_c, h_new))
+    return out, stacked
