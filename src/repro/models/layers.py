@@ -33,10 +33,11 @@ from repro.core import capture as capture_mod
 from repro.core.config import MemoryControllerConfig
 
 
-def rms_norm(x: jnp.ndarray, w: jnp.ndarray, eps: float = 1e-6):
+def rms_norm(x: jnp.ndarray, w: jnp.ndarray, eps: float = 1e-6,
+             axis=-1):
     dtype = x.dtype
     x = x.astype(jnp.float32)
-    x = x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps)
+    x = x * jax.lax.rsqrt(jnp.mean(x * x, axis=axis, keepdims=True) + eps)
     return (x * w.astype(jnp.float32)).astype(dtype)
 
 
