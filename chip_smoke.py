@@ -285,9 +285,8 @@ def phase_simulator(cases=GOLDEN_CASES) -> None:
     table = jnp.arange((int(rows.max()) + 1) * 8,
                        dtype=jnp.float32).reshape(-1, 8)
     t = time.perf_counter()
-    _, hits, lines = cache_engine.simulate_trace(
-        cache_engine.init_cache(config.cache, 8), lids, table,
-        engine="sequential")
+    _, hits, lines = cache_engine.simulate_trace_seq(
+        cache_engine.init_cache(config.cache, 8), lids, table)
     want, rate = cache_engine.hit_rate_oracle_seq(config.cache, rows)
     check(np.array_equal(np.asarray(hits), want),
           "cache engine scan: hits differ from the LRU oracle")

@@ -66,7 +66,7 @@ def _score(
 # Batched grid scorer (the vmap axis over stacked configs)
 # ---------------------------------------------------------------------------
 #
-# ``tune``'s one-at-a-time path rebuilds the stream, re-plans the batch
+# ``tune_seq``'s one-at-a-time walk rebuilds the stream, re-plans the batch
 # former (twice: once to schedule, once to count) and re-classifies the
 # served stream for every grid point — all python-per-batch work on axes
 # that are algebraically redundant:
@@ -283,10 +283,42 @@ def _batched_scores(
     return scores
 
 
-def tune(
+def tune(row_ids: np.ndarray, row_bytes: int, **grid) -> TuneResult:
+    """Grid-search TUNE parameters for a trace under a VMEM budget.
+
+    ``grid`` narrows the search space; its keywords and defaults are
+    :func:`_tune`'s. ``num_channels`` × ``mapping_policies`` extend the
+    grid with the multi-channel front end's axes (``ChannelConfig``);
+    the defaults keep the paper's single-interface search space. With
+    one channel every mapping policy is the identity, so only the first
+    policy is scored.
+
+    ``dram_sched_policies`` × ``reorder_windows`` add the DRAM command
+    scheduler's axes (``DRAMSchedConfig``): FIFO never reorders, so it
+    is scored at one window only, and window 1 collapses every policy
+    to FIFO — redundant grid points are deduplicated before scoring.
+
+    The whole grid is scored as one stacked computation (see
+    ``_batched_scores`` — the dma axis is hoisted, the batch plan
+    vectorized, and the strict-FIFO service term classified by one
+    fused key sort per variant), bit-identical to :func:`tune_seq`.
+    """
+    return _tune(row_ids, row_bytes, batched=True, **grid)
+
+
+def tune_seq(row_ids: np.ndarray, row_bytes: int, **grid) -> TuneResult:
+    """Reference for :func:`tune`: the same grid, every candidate scored
+    one at a time through the staged pipeline (:func:`_score`). Tables,
+    argmin and candidate count are property-tested bit-identical
+    (``tests/core/test_autotune.py``)."""
+    return _tune(row_ids, row_bytes, batched=False, **grid)
+
+
+def _tune(
     row_ids: np.ndarray,
     row_bytes: int,
     *,
+    batched: bool,
     vmem_budget_bytes: int = 8 << 20,
     batch_sizes: Sequence[int] = (4, 8, 16, 32, 64, 128, 256, 512),
     associativities: Sequence[int] = (1, 2, 4, 8),
@@ -299,32 +331,8 @@ def tune(
     starvation_cap: int = 16,
     enable_cache: bool = True,
     timings: DRAMTimings = DDR4_2400,
-    engine: str = "batched",
 ) -> TuneResult:
-    """Grid-search TUNE parameters for a trace under a VMEM budget.
-
-    ``num_channels`` × ``mapping_policies`` extend the grid with the
-    multi-channel front end's axes (``ChannelConfig``); the defaults keep
-    the paper's single-interface search space. With one channel every
-    mapping policy is the identity, so only the first policy is scored.
-
-    ``dram_sched_policies`` × ``reorder_windows`` add the DRAM command
-    scheduler's axes (``DRAMSchedConfig``): FIFO never reorders, so it
-    is scored at one window only, and window 1 collapses every policy
-    to FIFO — redundant grid points are deduplicated before scoring.
-
-    ``engine`` selects the scorer: ``"batched"`` (default) evaluates the
-    whole grid as one stacked computation (see ``_batched_scores`` — the
-    dma axis is hoisted, the batch plan vectorized, and the strict-FIFO
-    service term classified by one fused key sort per variant);
-    ``"oracle"`` scores candidates one at a time through the staged
-    pipeline. Both return bit-identical scores, tables and argmin
-    (property-tested in ``tests/core/test_autotune.py``).
-    """
     row_ids = np.asarray(row_ids)
-    if engine not in ("batched", "oracle"):
-        raise ValueError(f"unknown tune engine {engine!r} "
-                         "(expected 'batched' or 'oracle')")
     best_cfg, best_cycles, table = None, float("inf"), []
     n_eval = 0
     cache_grid = (
@@ -342,7 +350,7 @@ def tune(
     # across the whole grid via this shared dict.
     filter_memo: dict = {}
     scores = None
-    if engine == "batched":
+    if batched:
         scores = _batched_scores(
             row_ids, row_bytes, timings, batch_sizes=batch_sizes,
             cache_grid=cache_grid, chan_grid=chan_grid,
@@ -406,13 +414,13 @@ def sweep_serving_loads(
 ) -> list:
     """Batched open-loop load sweep: one trace, many arrival processes.
 
-    The ``perf_serving`` offered-load sweep re-ingests and re-validates
-    the same trace once per load point when driven through
-    ``MemoryController.simulate``; this evaluates the whole stacked
-    sweep in one call — the request stream is built and validated once,
-    and each load point swaps in its arrival stamps and runs the
-    open-loop serving pipeline. Per-point :class:`PipelineResult`\\ s are
-    bit-identical to the one-at-a-time path (property-tested in
+    An offered-load sweep driven through ``MemoryController.simulate``
+    re-ingests and re-validates the same trace once per load point;
+    this evaluates the whole stacked sweep in one call — the request
+    stream is built and validated once, and each load point swaps in
+    its arrival stamps and runs the open-loop serving pipeline.
+    Per-point :class:`PipelineResult`\\ s are bit-identical to the
+    one-at-a-time path (property-tested in
     ``tests/core/test_autotune.py``).
     """
     base = RequestStream.from_rows(row_ids, rw, row_bytes=row_bytes,

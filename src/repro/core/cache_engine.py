@@ -9,7 +9,8 @@ update, and (on miss) the MEM-pipeline fill of the victim way. MEM-pipeline
 priority (fills stall lookups) is inherent in the sequential scan semantics.
 
 This module is the *oracle* for the `repro.kernels.cache_lookup` Pallas
-kernel and the measurement substrate for the Table III / Fig. 7 benchmarks.
+kernel and the measurement substrate for the Table III / Fig. 7 cases of
+``tests/core/test_paper_figures.py``.
 Address mapping: line = addr // line_bytes, set = line % num_sets,
 tag = line // num_sets.
 """
@@ -129,7 +130,6 @@ def simulate_trace_seq(
 
 def simulate_trace(
     state: CacheState, line_ids: jnp.ndarray, table: jnp.ndarray,
-    *, engine: str = "auto",
 ) -> Tuple[CacheState, jnp.ndarray, jnp.ndarray]:
     """Service a *read* trace through the cache against backing ``table``.
 
@@ -138,23 +138,14 @@ def simulate_trace(
     write-back port — flush dirty state first, or use
     :func:`simulate_trace_rw` for mixed traces.
 
-    ``engine`` selects the execution strategy — never the semantics (the
-    two are bit-identical, see ``trace_engine``):
-
-    * ``"auto"`` (default) — set-parallel engine when the trace is
-      concrete, long enough, and the starting state is dirty-free (this
-      path's no-write-back-port contract); sequential scan otherwise.
-    * ``"parallel"`` — force the set-parallel engine.
-    * ``"sequential"`` — force the one-beat-per-request reference scan.
+    The input chooses the execution strategy, never the semantics (the
+    two are bit-identical, see ``trace_engine``): the set-parallel
+    engine when the trace is concrete, long enough, and the starting
+    state is dirty-free (this path's no-write-back-port contract); the
+    one-beat-per-request scan :func:`simulate_trace_seq` otherwise.
     """
     from repro.core import trace_engine
 
-    if engine == "sequential":
-        return simulate_trace_seq(state, line_ids, table)
-    if engine == "parallel":
-        return trace_engine.simulate_trace_parallel(state, line_ids, table)
-    if engine != "auto":
-        raise ValueError(f"unknown engine {engine!r}")
     if trace_engine.auto_parallel_ok(state, line_ids, table=table):
         return trace_engine.simulate_trace_parallel(state, line_ids, table)
     return simulate_trace_seq(state, line_ids, table)
@@ -267,7 +258,6 @@ def simulate_trace_rw(
     table: jnp.ndarray,
     *,
     config: CacheConfig,
-    engine: str = "auto",
 ) -> Tuple[CacheState, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Service a mixed read/write trace through the cache.
 
@@ -276,24 +266,16 @@ def simulate_trace_rw(
     lines) — call :func:`flush` on the final state to push residual dirty
     lines so ``table'`` matches the naive in-order write stream.
 
-    ``engine``: ``"auto"`` / ``"parallel"`` / ``"sequential"`` — execution
-    strategy only; results are bit-identical (see ``trace_engine``). The
-    parallel engine additionally requires every line id to fall inside
-    the table (``0 <= lid < table.shape[0]``) and uniform
-    table/data/payload dtypes, so its vectorized value reconstruction is
-    exact; ``"auto"`` checks this and falls back.
+    The input chooses the execution strategy only; results are
+    bit-identical (see ``trace_engine``). The set-parallel engine runs
+    when, besides :func:`simulate_trace`'s conditions, every line id
+    falls inside the table (``0 <= lid < table.shape[0]``) and the
+    table/data/payload dtypes agree, so its vectorized value
+    reconstruction is exact; otherwise :func:`simulate_trace_rw_seq`.
     """
     from repro.core import trace_engine
 
     wb = config.write_policy == "write_back"
-    if engine == "sequential":
-        return simulate_trace_rw_seq(state, line_ids, rw, write_lines,
-                                     table, config=config)
-    if engine == "parallel":
-        return trace_engine.simulate_trace_rw_parallel(
-            state, line_ids, rw, write_lines, table, write_back=wb)
-    if engine != "auto":
-        raise ValueError(f"unknown engine {engine!r}")
     if trace_engine.auto_parallel_ok(state, line_ids, rw=rw,
                                      write_lines=write_lines, table=table,
                                      rw_path=True):
@@ -480,7 +462,6 @@ def filter_trace_rw_seq(
 
 def filter_trace_rw(
     config: CacheConfig, line_ids: np.ndarray, rw: np.ndarray | None = None,
-    *, engine: str = "auto",
 ) -> FilterResult:
     """Cache filter for the staged pipeline: classify a mixed read/write
     line trace, *remove* requests the cache absorbs, and emit the victim
@@ -502,21 +483,34 @@ def filter_trace_rw(
     the lockstep state. Tiny or chain-dominated traces dispatch to the
     sequential oracle.
     """
-    if engine not in ("auto", "parallel", "sequential"):
-        raise ValueError(f"unknown engine {engine!r}")
+    lids = np.asarray(line_ids, dtype=np.int64).ravel()
+    lay = _CompactLayout(lids, config.num_sets)
+    if not lay.worthwhile:                        # skewed/tiny: dict wins
+        return filter_trace_rw_seq(config, lids, rw)
+    return _filter_lockstep(config, lids, rw, lay)
+
+
+def filter_trace_rw_parallel(
+    config: CacheConfig, line_ids: np.ndarray, rw: np.ndarray | None = None,
+) -> FilterResult:
+    """The lockstep walk of :func:`filter_trace_rw` on any trace, however
+    small or skewed — the path the property tests hold bit-identical to
+    :func:`filter_trace_rw_seq`."""
+    lids = np.asarray(line_ids, dtype=np.int64).ravel()
+    if lids.shape[0] == 0:
+        return _empty_filter_result(0)
+    return _filter_lockstep(config, lids, rw,
+                            _CompactLayout(lids, config.num_sets))
+
+
+def _filter_lockstep(config: CacheConfig, lids: np.ndarray,
+                     rw: np.ndarray | None,
+                     lay: _CompactLayout) -> FilterResult:
     sets, ways = config.num_sets, config.associativity
     wb = config.write_policy == "write_back"
-    lids = np.asarray(line_ids, dtype=np.int64).ravel()
     n = lids.shape[0]
-    if n == 0:
-        return _empty_filter_result(0)
     rw_arr = np.zeros(n, np.int32) if rw is None \
         else np.asarray(rw, dtype=np.int32).ravel()
-    if engine == "sequential":
-        return filter_trace_rw_seq(config, lids, rw_arr)
-    lay = _CompactLayout(lids, sets)
-    if engine == "auto" and not lay.worthwhile:   # skewed/tiny: dict wins
-        return filter_trace_rw_seq(config, lids, rw_arr)
     lay.build()
     K = lay.K
     tag_pad = lay.pad(lay.tag, np.int64)
@@ -616,8 +610,9 @@ def hit_rate_oracle(
 ) -> Tuple[np.ndarray, float]:
     """Fast numpy LRU-cache reference (no data movement) — hit mask + rate.
 
-    Used by benchmarks where only the hit/miss classification feeds the
-    timing model (Eq. 2) and by hypothesis tests as an independent oracle.
+    Used where only the hit/miss classification feeds the timing model
+    (Eq. 2, the Fig. 7 cases) and by hypothesis tests as an independent
+    oracle.
 
     Set-parallel vectorization: all sets advance in lockstep over their
     per-set subtraces (padded to the longest), with numpy ``(sets, ways)``

@@ -37,8 +37,8 @@ batch formers: they decide *order* and *cost*, never values.
 
 Since the staged-pipeline refactor (``repro.core.pipeline``, ARCHITECTURE
 §7) the *fast paths* of the two front-end compositions below delegate to
-pipeline stage subsets; the ``use_seq_oracle=True`` compositions keep the
-original request-at-a-time code and remain the bit-identity oracles.
+pipeline stage subsets; their ``*_seq`` siblings keep the original
+request-at-a-time code and remain the bit-identity oracles.
 """
 
 from __future__ import annotations
@@ -588,35 +588,27 @@ def simulate_channels(
 # Front-end pipelines: mapping (+ arbitration) (+ scheduling) → channels
 # ---------------------------------------------------------------------------
 
-def _run_channel(local_ch, rw_ch, *, sched_config, timings,
-                 coalesce_writes, use_seq_oracle, dram_sched=None):
-    """One channel's back half — optional scheduler front end, then the
-    open-row simulation — with ``use_seq_oracle`` swapping every stage
-    for its request-at-a-time sibling. Since the fast paths moved into
-    ``repro.core.pipeline`` this runs only as the oracle composition
-    (``use_seq_oracle=True``) the pipeline is property-tested against;
-    the flag is kept so the two compositions stay diffable."""
+def _run_channel_seq(local_ch, rw_ch, *, sched_config, timings,
+                     coalesce_writes, dram_sched=None):
+    """One channel's back half in the request-at-a-time oracle
+    compositions: the optional scheduler front end
+    (``schedule_trace_rw_seq``), then the open-row simulation (the
+    per-request channel walk, or the DRAM command scheduler's oracle
+    when it reorders or refreshes)."""
     from repro.core import scheduler as sched
 
     if sched_config is not None:
-        schedule = (sched.schedule_trace_rw_seq if use_seq_oracle
-                    else sched.schedule_trace_rw)
-        served, served_rw = schedule(local_ch, rw_ch, config=sched_config,
-                                     timings=timings,
-                                     coalesce_writes=coalesce_writes)
+        served, served_rw = sched.schedule_trace_rw_seq(
+            local_ch, rw_ch, config=sched_config, timings=timings,
+            coalesce_writes=coalesce_writes)
     else:
         served, served_rw = local_ch, rw_ch
-    if use_seq_oracle:
-        if dram_sched is not None and (dram_sched.effective_window > 1
-                                       or dram_sched.t_refi):
-            return simulate_dram_sched_seq(served, timings, dram_sched,
-                                           rw=served_rw)
-        return simulate_channels_seq(served, timings, ChannelConfig(),
-                                     rw=served_rw).per_channel[0]
-    if dram_sched is not None:
-        return simulate_dram_sched(served, timings, dram_sched,
-                                   rw=served_rw)
-    return simulate_dram_access(served, timings, rw=served_rw)
+    if dram_sched is not None and (dram_sched.effective_window > 1
+                                   or dram_sched.t_refi):
+        return simulate_dram_sched_seq(served, timings, dram_sched,
+                                       rw=served_rw)
+    return simulate_channels_seq(served, timings, ChannelConfig(),
+                                 rw=served_rw).per_channel[0]
 
 
 def schedule_and_simulate_channels(
@@ -627,7 +619,6 @@ def schedule_and_simulate_channels(
     timings: DRAMTimings = DDR4_2400,
     channel_cfg: ChannelConfig = ChannelConfig(),
     coalesce_writes: bool = False,
-    use_seq_oracle: bool = False,
     dram_sched: DRAMSchedConfig | None = None,
 ) -> ChannelSimResult:
     """Single-port multi-channel pipeline: map → per-channel scheduler
@@ -635,25 +626,37 @@ def schedule_and_simulate_channels(
     each channel owns a DRAM interface) → per-channel open-row
     simulation → makespan aggregate.
 
-    The fast path is the staged pipeline (``repro.core.pipeline``:
-    AddressMap → BatchScheduler → DRAMService) viewed through the
-    legacy aggregate. ``use_seq_oracle`` keeps the original
-    request-at-a-time composition (``schedule_trace_rw_seq`` +
-    per-request classification) — the pre-refactor code the pipeline is
-    property-tested bit-identical against. ``dram_sched`` gives every
-    channel's interface the out-of-order command scheduler (oracle
-    sibling on the seq path).
+    Runs the staged pipeline (``repro.core.pipeline``: AddressMap →
+    BatchScheduler → DRAMService) viewed through the legacy aggregate,
+    property-tested bit-identical to
+    :func:`schedule_and_simulate_channels_seq`. ``dram_sched`` gives
+    every channel's interface the out-of-order command scheduler.
     """
-    if not use_seq_oracle:
-        from repro.core import pipeline as pipeline_mod
-        stream = pipeline_mod.RequestStream.from_addrs(addrs, rw)
-        ctx = pipeline_mod.PipelineContext(
-            channels=channel_cfg, scheduler=sched_config, cache=None,
-            timings=timings, dram_sched=dram_sched)
-        return pipeline_mod.run_pipeline(
-            stream, ctx, pipeline_mod.default_stages(
-                ctx, cache=False, coalesce_writes=coalesce_writes)
-        ).as_channel_result()
+    from repro.core import pipeline as pipeline_mod
+    stream = pipeline_mod.RequestStream.from_addrs(addrs, rw)
+    ctx = pipeline_mod.PipelineContext(
+        channels=channel_cfg, scheduler=sched_config, cache=None,
+        timings=timings, dram_sched=dram_sched)
+    return pipeline_mod.run_pipeline(
+        stream, ctx, pipeline_mod.default_stages(
+            ctx, cache=False, coalesce_writes=coalesce_writes)
+    ).as_channel_result()
+
+
+def schedule_and_simulate_channels_seq(
+    addrs: np.ndarray,
+    rw: np.ndarray | None = None,
+    *,
+    sched_config: SchedulerConfig,
+    timings: DRAMTimings = DDR4_2400,
+    channel_cfg: ChannelConfig = ChannelConfig(),
+    coalesce_writes: bool = False,
+    dram_sched: DRAMSchedConfig | None = None,
+) -> ChannelSimResult:
+    """Request-at-a-time oracle for :func:`schedule_and_simulate_channels`:
+    the original composition (``schedule_trace_rw_seq`` + per-request
+    classification, the command scheduler's oracle sibling under
+    ``dram_sched``), per channel."""
     amap = AddressMap(channel_cfg, timings)
     addrs = np.asarray(addrs, dtype=np.int64).ravel()
     rw_arr = np.zeros(addrs.shape[0], np.int32) if rw is None \
@@ -663,10 +666,10 @@ def schedule_and_simulate_channels(
     per_channel, counts = [], []
     for k in range(channel_cfg.num_channels):
         sel = np.flatnonzero(ch == k)
-        per_channel.append(_run_channel(
+        per_channel.append(_run_channel_seq(
             local[sel], rw_arr[sel], sched_config=sched_config,
             timings=timings, coalesce_writes=coalesce_writes,
-            use_seq_oracle=True, dram_sched=dram_sched))
+            dram_sched=dram_sched))
         counts.append(int(sel.shape[0]))
     return _aggregate(per_channel, counts, 0.0)
 
@@ -683,7 +686,6 @@ def simulate_multiport_channels(
     channel_cfg: ChannelConfig = ChannelConfig(),
     sched_config: SchedulerConfig | None = None,
     coalesce_writes: bool = False,
-    use_seq_oracle: bool = False,
     dram_sched: DRAMSchedConfig | None = None,
 ) -> ChannelSimResult:
     """Full front end: per-PE streams → per-channel arbiter → optional
@@ -697,27 +699,41 @@ def simulate_multiport_channels(
     channel arbiters: grants and stall slots sum, and ``fairness`` is
     the Jain index of the aggregated per-port grant counts.
 
-    The fast path is the staged pipeline (``repro.core.pipeline``:
-    AddressMap → PortArbiter → BatchScheduler → DRAMService) viewed
-    through the legacy aggregate. ``use_seq_oracle`` keeps the original
-    all-sequential composition (``arbitrate_ports_seq`` /
-    ``schedule_trace_rw_seq`` / per-request channel walk) — the
-    pre-refactor code the pipeline is property-tested bit-identical
-    against.
+    Runs the staged pipeline (``repro.core.pipeline``: AddressMap →
+    PortArbiter → BatchScheduler → DRAMService) viewed through the
+    legacy aggregate, property-tested bit-identical to
+    :func:`simulate_multiport_channels_seq`.
     """
-    if not use_seq_oracle:
-        from repro.core import pipeline as pipeline_mod
-        stream = pipeline_mod.RequestStream.from_addrs(addrs, rw,
-                                                       pe_id=pe_id)
-        ctx = pipeline_mod.PipelineContext(
-            channels=channel_cfg, scheduler=sched_config, cache=None,
-            timings=timings, dram_sched=dram_sched)
-        return pipeline_mod.run_pipeline(
-            stream, ctx, pipeline_mod.default_stages(
-                ctx, ports=num_ports, arbiter_policy=policy,
-                weights=weights, cache=False,
-                coalesce_writes=coalesce_writes)
-        ).as_channel_result()
+    from repro.core import pipeline as pipeline_mod
+    stream = pipeline_mod.RequestStream.from_addrs(addrs, rw, pe_id=pe_id)
+    ctx = pipeline_mod.PipelineContext(
+        channels=channel_cfg, scheduler=sched_config, cache=None,
+        timings=timings, dram_sched=dram_sched)
+    return pipeline_mod.run_pipeline(
+        stream, ctx, pipeline_mod.default_stages(
+            ctx, ports=num_ports, arbiter_policy=policy,
+            weights=weights, cache=False,
+            coalesce_writes=coalesce_writes)
+    ).as_channel_result()
+
+
+def simulate_multiport_channels_seq(
+    pe_id: np.ndarray,
+    addrs: np.ndarray,
+    rw: np.ndarray | None = None,
+    *,
+    num_ports: int,
+    policy: str = "round_robin",
+    weights: Sequence[int] | None = None,
+    timings: DRAMTimings = DDR4_2400,
+    channel_cfg: ChannelConfig = ChannelConfig(),
+    sched_config: SchedulerConfig | None = None,
+    coalesce_writes: bool = False,
+    dram_sched: DRAMSchedConfig | None = None,
+) -> ChannelSimResult:
+    """Request-at-a-time oracle for :func:`simulate_multiport_channels`:
+    the original all-sequential composition (``arbitrate_ports_seq`` /
+    ``schedule_trace_rw_seq`` / per-request channel walk)."""
     amap = AddressMap(channel_cfg, timings)
     addrs = np.asarray(addrs, dtype=np.int64).ravel()
     pe = np.asarray(pe_id, dtype=np.int64).ravel()
@@ -727,22 +743,21 @@ def simulate_multiport_channels(
         else np.asarray(rw, np.int32).ravel()
     ch = amap.channel_of(addrs)
     local = amap.local_addr(addrs)
-    arbitrate = arbitrate_ports_seq
 
     per_channel, counts = [], []
     grants = np.zeros(num_ports, dtype=np.int64)
     stalls = np.zeros(num_ports, dtype=np.int64)
     for k in range(channel_cfg.num_channels):
         sel = np.flatnonzero(ch == k)
-        perm, stats = arbitrate(pe[sel], num_ports=num_ports,
-                                policy=policy, weights=weights)
+        perm, stats = arbitrate_ports_seq(pe[sel], num_ports=num_ports,
+                                          policy=policy, weights=weights)
         order = sel[perm]
         grants += stats.grants
         stalls += stats.stall_slots
-        per_channel.append(_run_channel(
+        per_channel.append(_run_channel_seq(
             local[order], rw_arr[order], sched_config=sched_config,
             timings=timings, coalesce_writes=coalesce_writes,
-            use_seq_oracle=use_seq_oracle, dram_sched=dram_sched))
+            dram_sched=dram_sched))
         counts.append(int(sel.shape[0]))
     port_stats = ArbiterStats(grants=grants, stall_slots=stalls,
                               fairness=_jain(grants))
@@ -781,22 +796,10 @@ class ServingChannelResult(ChannelSimResult):
         return self.completion_fpga_cycles - self.arrival_fpga_cycles
 
 
-def simulate_serving_channels(
-    addrs: np.ndarray,
-    arrival_fpga: np.ndarray | None = None,
-    rw: np.ndarray | None = None,
-    *,
-    pe_id: np.ndarray | None = None,
-    num_ports: int | None = None,
-    policy: str = "round_robin",
-    weights: Sequence[int] | None = None,
-    timings: DRAMTimings = DDR4_2400,
-    channel_cfg: ChannelConfig = ChannelConfig(),
-    dram_sched: DRAMSchedConfig | None = None,
-    use_seq_oracle: bool = False,
-    faults=None,
-    trace=None,
-) -> ServingChannelResult:
+def simulate_serving_channels(addrs: np.ndarray,
+                              arrival_fpga: np.ndarray | None = None,
+                              rw: np.ndarray | None = None,
+                              **kw) -> ServingChannelResult:
     """Arrival-aware front end: map → per-channel coupled
     admission+service (:func:`repro.core.timing.simulate_arrivals`) →
     makespan/latency aggregate.
@@ -804,10 +807,15 @@ def simulate_serving_channels(
     Channels stay exactly independent after mapping (each owns its
     arbiter, reorder window and refresh counter), so the open-loop walk
     decomposes per channel like every closed-loop composition above.
-    ``use_seq_oracle`` swaps every channel's engine for the
-    request-at-a-time spec ``simulate_arrivals_seq`` — the two are
-    bit-identical (property-tested), and with all-zero arrivals both
-    degenerate to the closed-loop arbiter + scheduler results.
+    Each channel runs the fast engine
+    (:func:`repro.core.timing.simulate_arrivals`), bit-identical to
+    :func:`simulate_serving_channels_seq` (property-tested); with
+    all-zero arrivals both degenerate to the closed-loop arbiter +
+    scheduler results.
+
+    Keywords: ``pe_id``, ``num_ports``, ``policy`` (``"round_robin"``),
+    ``weights``, ``timings`` (``DDR4_2400``), ``channel_cfg``,
+    ``dram_sched``, ``faults`` and ``trace``.
 
     ``faults`` (a :class:`repro.core.config.FaultConfig`) turns on the
     RAS layer: the address map re-homes failed channels' traffic onto
@@ -825,7 +833,39 @@ def simulate_serving_channels(
     bit-identical.
     """
     from repro.core.timing import simulate_arrivals, simulate_faults
+    return _serving_channels(simulate_arrivals, simulate_faults, addrs,
+                             arrival_fpga, rw, **kw)
 
+
+def simulate_serving_channels_seq(addrs: np.ndarray,
+                                  arrival_fpga: np.ndarray | None = None,
+                                  rw: np.ndarray | None = None,
+                                  **kw) -> ServingChannelResult:
+    """Request-at-a-time oracle for :func:`simulate_serving_channels`:
+    the same composition with every channel served by the spec engines
+    ``simulate_arrivals_seq`` / ``simulate_faults_seq``."""
+    from repro.core.timing import simulate_arrivals_seq, simulate_faults_seq
+    return _serving_channels(simulate_arrivals_seq, simulate_faults_seq,
+                             addrs, arrival_fpga, rw, **kw)
+
+
+def _serving_channels(
+    simulate_arrivals,
+    simulate_faults,
+    addrs: np.ndarray,
+    arrival_fpga: np.ndarray | None = None,
+    rw: np.ndarray | None = None,
+    *,
+    pe_id: np.ndarray | None = None,
+    num_ports: int | None = None,
+    policy: str = "round_robin",
+    weights: Sequence[int] | None = None,
+    timings: DRAMTimings = DDR4_2400,
+    channel_cfg: ChannelConfig = ChannelConfig(),
+    dram_sched: DRAMSchedConfig | None = None,
+    faults=None,
+    trace=None,
+) -> ServingChannelResult:
     amap = AddressMap(channel_cfg, timings, faults)
     addrs = np.asarray(addrs, dtype=np.int64).ravel()
     n = addrs.shape[0]
@@ -835,7 +875,6 @@ def simulate_serving_channels(
     pe = None if pe_id is None else np.asarray(pe_id, np.int64).ravel()
     ch = amap.channel_of(addrs)
     local = amap.local_addr(addrs)
-    engine = "sequential" if use_seq_oracle else "auto"
     multi = num_ports is not None and num_ports > 1
 
     per_channel, counts = [], []
@@ -853,7 +892,6 @@ def simulate_serving_channels(
             arrival_fpga=arr[sel],
             pe_id=None if pe is None else pe[sel],
             num_ports=num_ports, arb_policy=policy, weights=weights,
-            engine=engine,
             trace=(None if trace is None
                    else trace.channel(k, req_ids=sel)))
         sched_k = dram_sched if dram_sched is not None \

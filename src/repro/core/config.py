@@ -428,8 +428,8 @@ class MemoryControllerConfig:
         """On-chip (VMEM) bytes claimed by the configured engines.
 
         FPGA URAM/BRAM consumption (Table III / Fig. 5 / Fig. 6) maps to the
-        VMEM working set on TPU. Used by benchmarks and by the autotuner's
-        resource constraint.
+        VMEM working set on TPU. Used by the autotuner's resource
+        constraint.
         """
         total = 0
         if self.cache.enabled:
@@ -515,7 +515,7 @@ PAPER_EVAL_CONFIG = MemoryControllerConfig(
 # engines composed with the 4-channel front end — the setting where the
 # paper's access-time wins come from the composition of the stages
 # rather than any stage alone (the `simulate()` pipeline's default
-# benchmark target; `benchmarks/perf_pipeline.py`).
+# target; `tests/core/test_paper_figures.py` pins it beating scheduler-only).
 PAPER_COMBINED_CONFIG = MemoryControllerConfig(
     cache=CacheConfig(line_width_bits=512, num_lines=4096, associativity=4),
     dma=DMAConfig(buffer_bytes=16 * 1024, num_parallel_dma=4),

@@ -525,7 +525,6 @@ class CacheFilterStage:
     an identical input stream).
     """
 
-    engine: str = "auto"
     memo: dict | None = None
     name: str = dataclasses.field(default="cache_filter", init=False)
 
@@ -549,7 +548,7 @@ class CacheFilterStage:
         for k, sel in _per_channel(stream, ctx.num_channels):
             sub = stream.select(sel)
             res = cache_engine.filter_trace_rw(
-                cache, sub.local_addr // lb, sub.rw, engine=self.engine)
+                cache, sub.local_addr // lb, sub.rw)
             ch_hits = int(res.hits.sum())
             n_hits += ch_hits
             n_wb += res.n_writebacks

@@ -11,8 +11,8 @@ to different addresses are reordered. A batch holds a single request type
 Two planes:
 
 * **Control plane** (`form_batches`) — host-side trace segmentation with the
-  timeout/full/type-change rules; numpy, used by benchmarks and the serving
-  scheduler.
+  timeout/full/type-change rules; numpy, used by the staged pipeline and
+  the serving scheduler.
 * **Data plane** (`reorder_batch` / `sort_requests`) — device-side stable
   key sort; dispatches to the Pallas bitonic kernel on TPU and to
   ``jnp.argsort(..., stable=True)`` elsewhere. The fused gather consumers
@@ -389,7 +389,8 @@ def schedule_trace(
     arrival_cycle: Sequence[int] | None = None,
 ) -> np.ndarray:
     """Run the full control plane over a trace; return the reordered
-    address stream as seen by the DRAM (used by the Fig. 7/9 benchmarks)."""
+    address stream as seen by the DRAM (used by the Fig. 7/9 cases of
+    ``tests/core/test_paper_figures.py``)."""
     return schedule_trace_rw(addrs, rw, config=config, timings=timings,
                              arrival_cycle=arrival_cycle)[0]
 

@@ -3,8 +3,8 @@
 Two roles:
 
 1. *Analytic model* — Equations 1-3 of the paper, used by the autotuner to
-   predict controller performance for a candidate configuration, and by the
-   benchmarks to reproduce Fig. 9.
+   predict controller performance for a candidate configuration, and by
+   ``tests/core/test_paper_figures.py`` to reproduce Fig. 9.
 
 2. *Cycle-level open-row DRAM simulator* — the measurement substrate for the
    paper-claim reproductions (Fig. 7: 27% GCN / 58% CNN, Fig. 8: 20x, Fig. 9:
@@ -607,7 +607,6 @@ def simulate_dram_sched(
     timings: DRAMTimings = DDR4_2400,
     sched: DRAMSchedConfig = DRAMSchedConfig(),
     rw: np.ndarray | None = None,
-    engine: str = "auto",
     trace=None,
 ) -> SchedSimResult:
     """Out-of-order DRAM command scheduling — vectorized, bit-identical
@@ -620,15 +619,12 @@ def simulate_dram_sched(
     ``repro.core.trace_engine`` (hit runs at array speed, one python
     event per serviced miss / refresh / forced starvation pick).
 
-    ``trace`` requests the lifecycle event stream: the sequential
-    engine emits natively, the fast engines reconstruct it from their
-    outputs after the timing run (``trace=None`` is the zero-overhead
-    hot path — no code on it changes).
+    ``trace`` requests the lifecycle event stream: the oracle
+    :func:`simulate_dram_sched_seq` emits natively, the fast engines
+    reconstruct it from their outputs after the timing run
+    (``trace=None`` is the zero-overhead hot path — no code on it
+    changes).
     """
-    if engine not in ("auto", "fast", "sequential"):
-        raise ValueError(f"engine={engine!r} must be auto|fast|sequential")
-    if engine == "sequential":
-        return simulate_dram_sched_seq(addrs, timings, sched, rw, trace)
     addrs = np.asarray(addrs, dtype=np.int64).ravel()
     n = addrs.size
     if n == 0:
@@ -972,7 +968,6 @@ def simulate_arrivals(
     num_ports: int | None = None,
     arb_policy: str = "round_robin",
     weights=None,
-    engine: str = "auto",
     trace=None,
 ) -> ServingSimResult:
     """Open-loop channel service — the fast engine, bit-identical to
@@ -984,13 +979,6 @@ def simulate_arrivals(
     optimized admission-coupled event loop. ``trace`` requests the
     lifecycle event stream (oracle-emitted or fast-path-reconstructed;
     ``trace=None`` is the unchanged hot path)."""
-    if engine not in ("auto", "fast", "sequential"):
-        raise ValueError(f"engine={engine!r} must be auto|fast|sequential")
-    if engine == "sequential":
-        return simulate_arrivals_seq(
-            addrs, timings, sched, rw, arrival_fpga=arrival_fpga,
-            pe_id=pe_id, num_ports=num_ports, arb_policy=arb_policy,
-            weights=weights, trace=trace)
     from repro.core import trace_engine
     return trace_engine.simulate_arrivals_fast(
         addrs, timings, sched, rw, arrival_fpga=arrival_fpga,
@@ -1407,7 +1395,6 @@ def simulate_faults(
     num_ports: int | None = None,
     arb_policy: str = "round_robin",
     weights=None,
-    engine: str = "auto",
     trace=None,
 ) -> FaultSimResult:
     """Fault-injected channel service — the fast engine, bit-identical
@@ -1418,13 +1405,6 @@ def simulate_faults(
     oracle emits too when nothing injects). ``trace`` requests the
     lifecycle event stream; ``trace=None`` is the unchanged hot
     path."""
-    if engine not in ("auto", "fast", "sequential"):
-        raise ValueError(f"engine={engine!r} must be auto|fast|sequential")
-    if engine == "sequential":
-        return simulate_faults_seq(
-            addrs, timings, sched, rw, faults=faults, channel=channel,
-            arrival_fpga=arrival_fpga, pe_id=pe_id, num_ports=num_ports,
-            arb_policy=arb_policy, weights=weights, trace=trace)
     if faults is None or not faults.injects:
         base = simulate_arrivals(
             addrs, timings, sched, rw, arrival_fpga=arrival_fpga,

@@ -27,8 +27,8 @@ ports onto ``config.num_pes`` and drops the logical arrival clock, so
 Pinned traces (one representative per model family) live as JSON under
 ``tests/goldens/traces/`` — regenerable with
 ``scripts/regen_goldens.py --traces`` — and feed the golden harness
-(``tests/core/golden_cases.py``) plus the per-family benchmark matrix
-(``benchmarks/perf_model_traces.py``).
+(``tests/core/golden_cases.py``) plus the per-family tuned-geometry case
+of ``tests/core/test_paper_figures.py``.
 """
 
 from __future__ import annotations

@@ -267,7 +267,7 @@ def mc_kv_append(buf: jnp.ndarray, new: jnp.ndarray, slot,
 
     A cache row is a contiguous page, so the append is classified as a
     bulk/streaming write (cache-bypassing), not an irregular scatter;
-    its DRAM cost is what ``benchmarks/fig7_write_workloads.py`` models.
+    its DRAM cost is the ``fig7_write_kv_append`` paper-figure test case.
     The data plane is the same for every engine setting; ``mc`` marks
     the request class, which the capture hook reports as ``kv_append``
     bulk-write records (op label suffixed ``_dma`` when the config's DMA

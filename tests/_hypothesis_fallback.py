@@ -1,7 +1,7 @@
 """Minimal stand-in for ``hypothesis`` when the real package is absent.
 
 The test suite uses a small slice of the hypothesis API (``given`` /
-``settings`` / a handful of strategies). Some execution environments for
+``example`` / ``settings`` / a handful of strategies). Some execution environments for
 this repo cannot install third-party packages, so ``tests/conftest.py``
 installs this deterministic fallback into ``sys.modules`` *only when the
 real library is missing*. With hypothesis installed (as in CI, see
@@ -68,10 +68,20 @@ def settings(max_examples: int = 10, deadline=None, **_ignored):
     return deco
 
 
+def example(*drawn):
+    """Pin one explicit example; ``given`` runs it before the draws."""
+    def deco(fn):
+        fn.__dict__.setdefault("_fallback_examples", []).append(drawn)
+        return fn
+    return deco
+
+
 def given(*strategies: _Strategy):
     def deco(fn):
         @functools.wraps(fn)
         def wrapper(*args, **kwargs):
+            for drawn in getattr(wrapper, "_fallback_examples", ()):
+                fn(*args, *drawn, **kwargs)
             n = getattr(wrapper, "_fallback_max_examples", 10)
             # Deterministic per-test stream: same examples every run.
             seed = zlib.crc32(fn.__qualname__.encode())
@@ -89,6 +99,7 @@ def given(*strategies: _Strategy):
 def install() -> None:
     """Register the fallback as ``hypothesis`` / ``hypothesis.strategies``."""
     mod = types.ModuleType("hypothesis")
+    mod.example = example
     mod.given = given
     mod.settings = settings
     strategies = types.ModuleType("hypothesis.strategies")

@@ -5,7 +5,7 @@ import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
-from repro.core.autotune import _score, sweep_serving_loads, tune
+from repro.core.autotune import _score, sweep_serving_loads, tune, tune_seq
 from repro.core.config import (CacheConfig, DRAMSchedConfig,
                                MemoryControllerConfig, SchedulerConfig)
 from repro.core.controller import MemoryController
@@ -174,7 +174,7 @@ def test_tune_serving_constrained_selection():
 def test_property_batched_tune_matches_oracle(workload, cache_axes,
                                               chan_axes, sched_axes,
                                               enable_cache, seed):
-    """tune(engine='batched') must reproduce tune(engine='oracle') bit
+    """tune (the batched scorer) must reproduce tune_seq bit
     for bit — every table entry, the argmin config, the modeled score
     and the candidate count — across cache/channel/sched grids, skew
     levels and cache-off runs."""
@@ -189,8 +189,8 @@ def test_property_batched_tune_matches_oracle(workload, cache_axes,
                  num_channels=n_chans, mapping_policies=mappings,
                  dram_sched_policies=spols, reorder_windows=wins,
                  enable_cache=enable_cache)
-    a = tune(rows, row_bytes, engine="oracle", **grids)
-    b = tune(rows, row_bytes, engine="batched", **grids)
+    a = tune_seq(rows, row_bytes, **grids)
+    b = tune(rows, row_bytes, **grids)
     assert a.table == b.table
     assert a.config == b.config
     assert a.modeled_cycles == b.modeled_cycles
@@ -204,24 +204,19 @@ def test_batched_tune_tiny_and_degenerate_traces():
     splitting)."""
     for rows in (np.asarray([7], np.int64),
                  np.asarray([3, 3, 9, 3, 11], np.int64)):
-        a = tune(rows, 4096, engine="oracle",
+        a = tune_seq(rows, 4096,
                  batch_sizes=(4, 64), associativities=(1,),
                  num_lines=(1024,), dma_channels=(1,),
                  num_channels=(1, 4),
                  dram_sched_policies=("fifo", "frfcfs"),
                  reorder_windows=(1, 8))
-        b = tune(rows, 4096, engine="batched",
+        b = tune(rows, 4096,
                  batch_sizes=(4, 64), associativities=(1,),
                  num_lines=(1024,), dma_channels=(1,),
                  num_channels=(1, 4),
                  dram_sched_policies=("fifo", "frfcfs"),
                  reorder_windows=(1, 8))
         assert a.table == b.table and a.config == b.config
-
-
-def test_tune_rejects_unknown_engine(trace):
-    with pytest.raises(ValueError, match="unknown tune engine"):
-        tune(trace, 512, engine="vmapped")
 
 
 # ---------------------------------------------------------------------------

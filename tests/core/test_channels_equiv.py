@@ -3,7 +3,7 @@
 Every stage of the new front end keeps a request-at-a-time sibling:
 ``simulate_channels_seq`` (global walk over interleaved per-channel
 bank/turnaround state), ``arbitrate_ports_seq`` (grant-per-slot loop over
-per-port FIFOs), and the ``use_seq_oracle`` composition of the full
+per-port FIFOs), and the ``*_seq`` compositions of the full
 pipeline (seq arbiter + seq scheduler + per-request DRAM walk). These
 property tests assert the fast paths are *bit-identical* across channel
 counts, mapping policies, arbiter policies, timings presets and
@@ -20,8 +20,10 @@ from repro.core.channels import (AddressMap, arbitrate_ports,
                                  arbitrate_ports_seq, arbiter_fill_cycles,
                                  per_port_order_preserved,
                                  schedule_and_simulate_channels,
+                                 schedule_and_simulate_channels_seq,
                                  simulate_channels, simulate_channels_seq,
-                                 simulate_multiport_channels)
+                                 simulate_multiport_channels,
+                                 simulate_multiport_channels_seq)
 from repro.core.config import (ChannelConfig, MemoryControllerConfig,
                                SchedulerConfig)
 from repro.core.controller import MemoryController
@@ -214,8 +216,7 @@ def test_property_multiport_pipeline_identical(reqs, num_channels,
                                             policy=map_policy),
                   sched_config=SchedulerConfig(batch_size=16))
     fast = simulate_multiport_channels(pe, addrs, rw, **kwargs)
-    ref = simulate_multiport_channels(pe, addrs, rw, use_seq_oracle=True,
-                                      **kwargs)
+    ref = simulate_multiport_channels_seq(pe, addrs, rw, **kwargs)
     _assert_channel_results_equal(fast, ref)
 
 
@@ -233,8 +234,7 @@ def test_property_scheduled_channel_pipeline_identical(reqs, num_channels,
                   channel_cfg=ChannelConfig(num_channels=num_channels),
                   coalesce_writes=coalesce)
     fast = schedule_and_simulate_channels(addrs, rw, **kwargs)
-    ref = schedule_and_simulate_channels(addrs, rw, use_seq_oracle=True,
-                                         **kwargs)
+    ref = schedule_and_simulate_channels_seq(addrs, rw, **kwargs)
     _assert_channel_results_equal(fast, ref)
 
 

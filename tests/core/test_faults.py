@@ -203,8 +203,8 @@ def test_dispatcher_engines_agree_on_storm():
     sched = DRAMSchedConfig(policy="frfcfs", reorder_window=16)
     kw = dict(rw=rw, faults=STORM, arrival_fpga=arr, pe_id=pe,
               num_ports=3, arb_policy="round_robin")
-    a = simulate_faults(addrs, DDR4_2400, sched, engine="fast", **kw)
-    b = simulate_faults(addrs, DDR4_2400, sched, engine="sequential", **kw)
+    a = simulate_faults(addrs, DDR4_2400, sched, **kw)
+    b = simulate_faults_seq(addrs, DDR4_2400, sched, **kw)
     _assert_fault_results_equal(a, b)
     assert a.fault.n_injected > 0          # the storm actually landed
 

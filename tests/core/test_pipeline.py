@@ -4,7 +4,7 @@ ingestion point.
 
 The legacy ``modeled_*`` entry points are compared against the
 *pre-refactor* compositions, which survive verbatim as the
-``use_seq_oracle=True`` paths in ``channels.py`` (and, for
+``*_seq`` compositions in ``channels.py`` (and, for
 ``modeled_gather_time``, as the inline seed formula).
 """
 
@@ -12,9 +12,12 @@ import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
-from repro.core.cache_engine import filter_trace_rw, filter_trace_rw_seq
-from repro.core.channels import (AddressMap, schedule_and_simulate_channels,
-                                 simulate_multiport_channels)
+from repro.core.cache_engine import (filter_trace_rw,
+                                     filter_trace_rw_parallel,
+                                     filter_trace_rw_seq)
+from repro.core.channels import (AddressMap,
+                                 schedule_and_simulate_channels_seq,
+                                 simulate_multiport_channels_seq)
 from repro.core.config import (CacheConfig, ChannelConfig,
                                MemoryControllerConfig, SchedulerConfig)
 from repro.core.controller import MemoryController
@@ -61,10 +64,9 @@ def test_property_modeled_access_time_unchanged(reqs, num_channels,
     mc = MemoryController(cfg, timings=HBM_V5E if hbm else DDR4_2400)
     new = mc.modeled_channel_access_time(rows, rw, 4096,
                                          coalesce_writes=coalesce)
-    old = schedule_and_simulate_channels(
+    old = schedule_and_simulate_channels_seq(
         rows * 4096, rw, sched_config=cfg.scheduler, timings=mc.timings,
-        channel_cfg=cfg.channels, coalesce_writes=coalesce,
-        use_seq_oracle=True)
+        channel_cfg=cfg.channels, coalesce_writes=coalesce)
     _assert_channel_results_equal(new, old)
     flat = mc.modeled_access_time(rows, rw, 4096, coalesce_writes=coalesce)
     assert flat.total_fpga_cycles == old.makespan_fpga_cycles
@@ -90,11 +92,10 @@ def test_property_modeled_multiport_unchanged(reqs, num_channels,
     new = mc.modeled_multiport_access_time(pe, rows, rw, 4096,
                                            policy=arb_policy,
                                            weights=weights)
-    old = simulate_multiport_channels(
+    old = simulate_multiport_channels_seq(
         pe, rows * 4096, rw, num_ports=4, policy=arb_policy,
         weights=weights, timings=mc.timings, channel_cfg=cfg.channels,
-        sched_config=cfg.scheduler if sched_on else None,
-        use_seq_oracle=True)
+        sched_config=cfg.scheduler if sched_on else None)
     _assert_channel_results_equal(new, old)
     np.testing.assert_array_equal(new.port_stats.grants,
                                   old.port_stats.grants)
@@ -183,7 +184,7 @@ def test_property_cache_filter_fast_vs_seq(reqs, shape, policy):
                       write_policy=policy)
     lids = np.asarray([r[0] for r in reqs], np.int64)
     rw = np.asarray([r[1] for r in reqs], np.int32)
-    fast = filter_trace_rw(cfg, lids, rw, engine="parallel")
+    fast = filter_trace_rw_parallel(cfg, lids, rw)
     ref = filter_trace_rw_seq(cfg, lids, rw)
     np.testing.assert_array_equal(fast.hits, ref.hits)
     np.testing.assert_array_equal(fast.keep, ref.keep)

@@ -30,7 +30,8 @@ from hypothesis import given, settings, strategies as st
 
 from repro.core import pipeline as pl
 from repro.core.channels import (ArbiterStats, arbitrate_ports_seq,
-                                 simulate_serving_channels)
+                                 simulate_serving_channels,
+                                 simulate_serving_channels_seq)
 from repro.core.config import ChannelConfig, DRAMSchedConfig
 from repro.core.timing import (DDR4_2400, HBM_V5E, simulate_arrivals,
                                simulate_arrivals_seq,
@@ -382,10 +383,8 @@ def test_serving_channels_fast_matches_seq_oracle():
                                          reorder_window=16,
                                          starvation_cap=8,
                                          t_refi=9363, t_rfc=420))
-    a = simulate_serving_channels(addrs, arr, rw, use_seq_oracle=True,
-                                  **kw)
-    b = simulate_serving_channels(addrs, arr, rw, use_seq_oracle=False,
-                                  **kw)
+    a = simulate_serving_channels_seq(addrs, arr, rw, **kw)
+    b = simulate_serving_channels(addrs, arr, rw, **kw)
     assert a.makespan_fpga_cycles == b.makespan_fpga_cycles
     assert (a.row_hits, a.row_conflicts, a.first_accesses) == \
            (b.row_hits, b.row_conflicts, b.first_accesses)

@@ -42,12 +42,12 @@ def _norm(events):
                   else x for x in e) for e in events]
 
 
-def _trace_pair(fn, *args, **kwargs):
-    """Run ``fn`` with engine sequential vs fast, each under a fresh
+def _trace_pair(fast_fn, seq_fn, *args, **kwargs):
+    """Run the fast engine and its ``*_seq`` oracle, each under a fresh
     ChannelTrace; assert results agree and return both event lists."""
     seq_t, fast_t = ChannelTrace(), ChannelTrace()
-    seq = fn(*args, engine="sequential", trace=seq_t, **kwargs)
-    fast = fn(*args, engine="fast", trace=fast_t, **kwargs)
+    seq = seq_fn(*args, trace=seq_t, **kwargs)
+    fast = fast_fn(*args, trace=fast_t, **kwargs)
     assert seq.total_fpga_cycles == fast.total_fpga_cycles
     return _norm(seq_t.events), _norm(fast_t.events)
 
@@ -77,7 +77,8 @@ def test_sched_events_fast_equals_oracle(policy, window, cap, t_rfc,
     sched = DRAMSchedConfig(policy=policy, reorder_window=window,
                             starvation_cap=cap, t_rfc=t_rfc,
                             t_refi=t_refi)
-    seq_ev, fast_ev = _trace_pair(timing.simulate_dram_sched, addrs,
+    seq_ev, fast_ev = _trace_pair(timing.simulate_dram_sched,
+                                  timing.simulate_dram_sched_seq, addrs,
                                   timing.DDR4_2400, sched, rw)
     assert seq_ev == fast_ev
     assert any(e[0] == "issue" for e in seq_ev)
@@ -104,7 +105,8 @@ def test_arrival_events_fast_equals_oracle(num_ports, arb, weights,
     sched = DRAMSchedConfig(policy="frfcfs", reorder_window=16,
                             t_rfc=420, t_refi=9363)
     seq_ev, fast_ev = _trace_pair(
-        timing.simulate_arrivals, addrs, timing.DDR4_2400, sched, rw,
+        timing.simulate_arrivals, timing.simulate_arrivals_seq, addrs,
+        timing.DDR4_2400, sched, rw,
         arrival_fpga=arr, pe_id=pe, num_ports=num_ports,
         arb_policy=arb, weights=weights)
     assert seq_ev == fast_ev
@@ -130,7 +132,8 @@ def test_fault_events_fast_equals_oracle(fc):
     sched = DRAMSchedConfig(policy="frfcfs_cap", reorder_window=32,
                             starvation_cap=8, t_rfc=420, t_refi=9363)
     seq_ev, fast_ev = _trace_pair(
-        timing.simulate_faults, addrs, timing.DDR4_2400, sched, rw,
+        timing.simulate_faults, timing.simulate_faults_seq, addrs,
+        timing.DDR4_2400, sched, rw,
         faults=fc, channel=0, arrival_fpga=arr, pe_id=pe, num_ports=2,
         arb_policy="weighted", weights=(4, 1))
     assert seq_ev == fast_ev

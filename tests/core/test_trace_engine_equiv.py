@@ -26,6 +26,8 @@ from repro.core.scheduler import (form_batches, form_batches_seq,
                                   schedule_trace_rw, schedule_trace_rw_seq)
 from repro.core.timing import (DDR4_2400, simulate_dram_access_windowed,
                                simulate_dram_access_windowed_seq)
+from repro.core.trace_engine import (simulate_trace_parallel,
+                                     simulate_trace_rw_parallel)
 
 
 def _assert_state_equal(a, b):
@@ -53,8 +55,7 @@ def test_property_read_trace_set_parallel_identical(lids, ways, warm):
             state, jnp.asarray(rng.integers(0, 1024, 64), jnp.int32), table)
     lids = jnp.asarray(lids, jnp.int32)
     f_seq, h_seq, l_seq = simulate_trace_seq(state, lids, table)
-    f_par, h_par, l_par = simulate_trace(state, lids, table,
-                                         engine="parallel")
+    f_par, h_par, l_par = simulate_trace_parallel(state, lids, table)
     _assert_state_equal(f_seq, f_par)
     np.testing.assert_array_equal(np.asarray(h_seq), np.asarray(h_par))
     np.testing.assert_array_equal(np.asarray(l_seq), np.asarray(l_par))
@@ -87,8 +88,9 @@ def test_property_rw_trace_set_parallel_identical(reqs, policy, ways, warm):
     rw = jnp.asarray([r[1] for r in reqs], jnp.int32)
     wlines = jnp.asarray(rng.standard_normal((n, 2)), jnp.float32)
     seq = simulate_trace_rw_seq(state, lids, rw, wlines, table, config=cfg)
-    par = simulate_trace_rw(state, lids, rw, wlines, table, config=cfg,
-                            engine="parallel")
+    par = simulate_trace_rw_parallel(
+        state, lids, rw, wlines, table,
+        write_back=cfg.write_policy == "write_back")
     _assert_state_equal(seq[0], par[0])
     np.testing.assert_array_equal(np.asarray(seq[1]), np.asarray(par[1]))
     np.testing.assert_array_equal(np.asarray(seq[2]), np.asarray(par[2]))
@@ -99,7 +101,7 @@ def test_property_rw_trace_set_parallel_identical(reqs, policy, ways, warm):
 
 
 def test_auto_dispatch_falls_back_and_stays_identical(rng):
-    """engine='auto' must be safe everywhere: tiny traces, out-of-table
+    """The input-chosen engine must be safe everywhere: tiny traces, out-of-table
     ids and dirty read-states take the sequential path transparently."""
     cfg = CacheConfig(num_lines=256, associativity=2)
     table = jnp.asarray(rng.standard_normal((64, 2)), jnp.float32)
@@ -115,7 +117,7 @@ def test_auto_dispatch_falls_back_and_stays_identical(rng):
 
 def test_auto_dispatch_incoherent_state_falls_back(rng):
     """A state warmed against a *different* table violates the
-    clean-line coherence precondition; engine='auto' must detect it and
+    clean-line coherence precondition; the engine choice must detect it and
     serve the seed semantics (hits serve the Data RAM copy, not the
     passed table)."""
     cfg = CacheConfig(num_lines=256, associativity=2)
@@ -279,7 +281,7 @@ def test_simulate_trace_auto_negative_ids_identical(rng):
 
 
 def test_simulate_trace_auto_is_jittable(rng):
-    """The seed read path ran inside jit; engine='auto' must keep that
+    """The seed read path ran inside jit; the engine choice must keep that
     working (traced table ⇒ sequential scan, no host round-trip)."""
     import jax
 
@@ -334,8 +336,8 @@ def test_property_single_request_trace_identical(lid, ways, lines):
     state = init_cache(cfg, 2)
     f_seq, h_seq, l_seq = simulate_trace_seq(
         state, jnp.asarray(ids, jnp.int32), table)
-    f_par, h_par, l_par = simulate_trace(
-        state, jnp.asarray(ids, jnp.int32), table, engine="parallel")
+    f_par, h_par, l_par = simulate_trace_parallel(
+        state, jnp.asarray(ids, jnp.int32), table)
     _assert_state_equal(f_seq, f_par)
     np.testing.assert_array_equal(np.asarray(h_seq), np.asarray(h_par))
     assert not bool(np.asarray(h_par)[0])
