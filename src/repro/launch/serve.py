@@ -28,14 +28,19 @@ no profiler runs, and add no device sync::
       serve.batch (batch)
         serve.prefill (batch)           _prefill and the first argmax
         serve.step (batch, step)        one per decode step
-          serve.read_tokens (batch)     the int(tok[i]) reads
           serve.dispatch (batch)        _decode, argmax, astype, cur + 1
+          serve.read_tokens (batch)     one read of the step's tokens,
+                                        after the next step is dispatched
       serve.model_memory                the modeled KV replay
 
 ``batch`` counts the server's batches across calls, so every span of one
-lockstep batch carries the same number in a trace viewer. ``ServeStats``
-keeps the same phases' host seconds, and ``Request.token_times`` the
-host time at which each token reached the host.
+lockstep batch carries the same number in a trace viewer. The decode loop
+keeps one step in flight: step ``k`` dispatches the decode that consumes
+step ``k``'s tokens before it reads them, so the device runs that decode
+while the host waits on the step before. ``ServeStats`` keeps the same
+phases' host seconds and counts the token transfers, and
+``Request.token_times`` the host time at which each token reached the
+host.
 
 CPU-runnable demo: ``python -m repro.launch.serve --arch yi-34b --smoke``.
 """
@@ -81,7 +86,7 @@ class BatchTimes:
 
     batch: int
     prefill_s: float    # prefill dispatch until the first token is read
-    decode_s: float     # first token read until the last step dispatched
+    decode_s: float     # first token read until the last token read
     read_s: float       # in ``serve.read_tokens`` (waits on the device)
     dispatch_s: float   # in ``serve.dispatch``
 
@@ -94,6 +99,7 @@ class ServeStats:
     prefill_tokens: int = 0     # padded: rows x the batch's longest prompt
     prompt_tokens: int = 0      # unpadded
     decode_slots: int = 0       # rows x decode executions
+    token_reads: int = 0        # device-to-host token transfers, one a step
     useful_tokens: int = 0      # tokens served
     #: bytes of decode state the last batch carried (its cache pytree's
     #: shapes): the KV cache of an attention model, the SSM and
@@ -184,27 +190,35 @@ class Server:
                 logits, cache, cur = self._prefill(
                     self.params, {"tokens": jnp.asarray(prompts)}, max_len)
                 tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                tok.copy_to_host_async()
             stats.state_bytes = sum(leaf.size * leaf.dtype.itemsize
                                     for leaf in jax.tree.leaves(cache))
             for step in range(max_new):
                 with TraceAnnotation("serve.step", batch=bid, step=step):
                     t0 = time.perf_counter()
-                    with TraceAnnotation("serve.read_tokens", batch=bid):
-                        for i, r in enumerate(batch):
-                            if step < r.max_new_tokens:
-                                outs[i].append(int(tok[i]))
-                                times[i].append(time.perf_counter())
-                    t1 = time.perf_counter()
                     with TraceAnnotation("serve.dispatch", batch=bid):
                         logits, cache = self._decode(self.params, tok,
                                                      cache, cur)
                         cur = cur + 1
-                        tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                        if step + 1 < max_new:      # the last goes unread
+                            nxt.copy_to_host_async()
+                    t1 = time.perf_counter()
+                    # the step just dispatched runs while the host waits
+                    # for its input, this step's tokens: one transfer
+                    with TraceAnnotation("serve.read_tokens", batch=bid):
+                        host = np.asarray(tok)
+                        stats.token_reads += 1
+                        for i, r in enumerate(batch):
+                            if step < r.max_new_tokens:
+                                outs[i].append(int(host[i]))
+                                times[i].append(time.perf_counter())
                     t2 = time.perf_counter()
-                read_s += t1 - t0
-                dispatch_s += t2 - t1
+                tok = nxt
+                dispatch_s += t1 - t0
+                read_s += t2 - t1
                 if step == 0:
-                    t_first = t1
+                    t_first = t2
             t_end = time.perf_counter()
         for r, o, t in zip(batch, outs, times):
             r.output = o
@@ -371,7 +385,8 @@ def main() -> None:
             print(f"[serve] {name} p50={p50:.1f}ms p95={p95:.1f}ms")
     print(f"[serve] decode slot use "
           f"{100 * stats.useful_tokens / max(1, stats.decode_slots):.1f}% "
-          f"({stats.useful_tokens}/{stats.decode_slots}); host token reads "
+          f"({stats.useful_tokens}/{stats.decode_slots}) in "
+          f"{stats.token_reads} token transfers; host token reads "
           f"{stats.read_s:.3f}s, dispatch {stats.dispatch_s:.3f}s, "
           f"modeled-memory replay {stats.model_memory_s:.3f}s")
     if stats.batch_times:

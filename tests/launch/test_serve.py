@@ -135,6 +135,7 @@ def test_serve_counters_and_token_times():
     assert stats.prompt_tokens == 5 + 7 + 4
     assert stats.prefill_tokens == 2 * 7 + 1 * 4      # padded
     assert stats.decode_steps == 3 + 4
+    assert stats.token_reads == 3 + 4       # one transfer a step
     assert stats.decode_slots == 2 * 3 + 1 * 4
     assert stats.useful_tokens == 3 + 2 + 4
     assert [b.batch for b in stats.batch_times] == [0, 1]
@@ -155,6 +156,83 @@ def test_serve_counters_and_token_times():
     # the batch counter runs on across calls
     server.serve([Request(**{**reqs[2].__dict__, "output": None})])
     assert server._batches == 3
+
+
+def _uneven_requests():
+    """Two batches whose rows finish at different steps: rids 0 and 1
+    (3 and 2 new tokens), then rids 2 and 3 (4 and 1) after the
+    timeout."""
+    return [Request(0, np.arange(5, dtype=np.int32), 3, 0),
+            Request(1, np.arange(7, dtype=np.int32) + 3, 2, 1),
+            Request(2, np.arange(4, dtype=np.int32) + 9, 4, 200),
+            Request(3, np.arange(6, dtype=np.int32) + 1, 1, 201)]
+
+
+def _step_by_step(server, batch):
+    """Greedy tokens of one batch from a plain loop: prefill, then in
+    turn the argmax, its read to the host and the next decode step."""
+    import jax.numpy as jnp
+    S = max(len(r.prompt) for r in batch)
+    steps = max(r.max_new_tokens for r in batch)
+    prompts = np.stack([np.pad(r.prompt, (S - len(r.prompt), 0))
+                        for r in batch])
+    logits, cache, cur = server._prefill(
+        server.params, {"tokens": jnp.asarray(prompts)}, S + steps + 8)
+    outs = [[] for _ in batch]
+    for step in range(steps):
+        tok = np.asarray(jnp.argmax(logits, axis=-1).astype(jnp.int32))
+        for o, r, t in zip(outs, batch, tok):
+            if step < r.max_new_tokens:
+                o.append(int(t))
+        logits, cache = server._decode(server.params, jnp.asarray(tok),
+                                       cache, cur)
+        cur = cur + 1
+    return outs
+
+
+@pytest.mark.parametrize("arch", ["h2o-danube-1.8b", "mamba2-2.7b"])
+def test_decode_loop_serves_the_step_by_step_tokens(arch):
+    """Keeping one decode step in flight changes no token: each request's
+    output is that of a plain step-by-step loop, one time per token,
+    rising."""
+    server = Server(arch, smoke=True)
+    reqs = _uneven_requests()
+    batches = server.admit(reqs)
+    assert [[r.rid for r in b] for b in batches] == [[0, 1], [2, 3]]
+    want = {r.rid: o for b in batches
+            for r, o in zip(b, _step_by_step(server, b))}
+    stats = server.serve(reqs)
+    assert stats.token_reads == stats.decode_steps == 3 + 4
+    for r in reqs:
+        assert r.output == want[r.rid], r.rid
+        assert len(r.token_times) == len(r.output) == r.max_new_tokens
+        assert all(np.diff(r.token_times) > 0)
+
+
+def test_decode_is_dispatched_before_its_tokens_are_read():
+    """Step ``k`` dispatches the decode that consumes its tokens before
+    they reach the host, for every row and step, and reads the batch's
+    tokens in one transfer a step."""
+    import time
+    server = Server("h2o-danube-1.8b", smoke=True)
+    reqs = _uneven_requests()
+    server.serve([Request(**r.__dict__) for r in reqs])     # compiles
+    decode = server._decode
+    calls = []
+
+    def timed(*args):
+        calls.append(time.perf_counter())
+        return decode(*args)
+
+    server._decode = timed
+    stats = server.serve(reqs)
+    assert stats.token_reads == stats.decode_steps == len(calls) == 3 + 4
+    first = 0
+    for batch in server.admit(reqs):
+        for r in batch:
+            for k, t in enumerate(r.token_times):
+                assert calls[first + k] < t, (r.rid, k)
+        first += max(r.max_new_tokens for r in batch)
 
 
 def test_decode_step_carries_the_controller_scopes():
@@ -189,7 +267,7 @@ def test_main_prints_the_counters(monkeypatch, capsys):
     assert "15 prefill tokens (100.0% prompt, the rest padding)" in out
     assert re.search(r"TTFT p50=[\d.]+ms p95=[\d.]+ms", out)
     assert re.search(r"TPOT p50=[\d.]+ms p95=[\d.]+ms", out)
-    assert "decode slot use 100.0% (9/9)" in out
+    assert "decode slot use 100.0% (9/9) in 3 token transfers" in out
     assert re.search(r"slowest batch 0: prefill to first token [\d.]+s, "
                      r"decode loop [\d.]+s \(token reads [\d.]+s, "
                      r"dispatch [\d.]+s\)", out)
